@@ -243,6 +243,8 @@ def main(quick: bool = False, limb_shards: int | None = None) -> str:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",

@@ -41,4 +41,6 @@ def main(quick: bool = False) -> str:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print(main())

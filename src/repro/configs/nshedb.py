@@ -12,8 +12,10 @@ the final aggregate psums over (pod, data).  That digit all-gather is
 the collective-bound part of the workload and hillclimb target #3.
 
 k = 32 limbs (instead of SEAL's 30) so limbs divide the 16-way model
-axis: logQ ~ 32 x 27.6 = 883 bits — the same HE-standard 128-bit budget
-as the paper's logQ = 881 (DESIGN.md §3 hardware-adaptation table).
+axis.  Note the engine's real k = 30 set (core/params.py paper_params)
+measures logQ = 899.5 bits with 30-bit primes, 18.5 bits above the
+HE-standard 881-bit bound for 128-bit security at n = 32768; at k = 32
+the same primes would give about 960 bits.
 """
 from __future__ import annotations
 

@@ -13,17 +13,27 @@ Layout conventions
 
 Batched evaluation path
 -----------------------
-Every arithmetic impl below is written against trailing (2, k, n) axes
-and broadcasts over any leading batch axes, so the same jitted code
-serves one ciphertext or a stacked column of blocks (one compilation per
-shape).  The limb-level hot loops — pointwise RNS mul/add/sub and the
-forward/inverse NTT — are routed through `core/limbops.LimbOps`, which
-dispatches to the Pallas kernels (`kernels/modops`, `kernels/ntt`) or to
-the pure-jnp `*_ref` oracles depending on the `backend` flag passed to
-`BFVContext` (default: the NSHEDB_LIMB_BACKEND env var, "auto" = Pallas
-on TPU, ref elsewhere; pass `interpret=True` to force kernel interpret
-mode on CPU).  Both paths produce bit-identical residues, so decryption
+Every op accepts one ciphertext or a stacked column of blocks.  The
+limb-level hot loops — pointwise RNS mul/add/sub and the forward/inverse
+NTT — are routed through `core/limbops.LimbOps`, which dispatches to the
+Pallas kernels (`kernels/modops`, `kernels/ntt`) or to the pure-jnp
+`*_ref` oracles depending on the `backend` flag passed to `BFVContext`
+(default: the NSHEDB_LIMB_BACKEND env var, "auto" = Pallas on TPU, ref
+elsewhere; kernels run compiled on a TPU and in interpret mode
+elsewhere).  Both paths produce bit-identical residues, so decryption
 results do not depend on the dispatch choice.
+
+The kernel-bearing programs — multiply, plaintext multiply, rotation,
+encryption, decryption — are compiled for one ciphertext and mapped
+over block lanes (`_lane_map`).  On one device the host runs the
+program once per lane, so it compiles once whatever the batch size.
+On a data mesh (engine/sharded.py places the tables there with
+`place_tables`) the program runs under `jax.shard_map`, each device
+mapping it over its own lanes: XLA cannot partition a Mosaic call, but
+a shard_map body is already per device.  The programs take both
+`LimbOps` (pytrees of the bases' tables) and the keys as arguments, so
+no table is embedded in a compiled program.  Elementwise ops stay
+batched (`_elementwise_j`).
 
 All deterministic arithmetic is jitted; sampling happens host-side with a
 seeded numpy Generator so tests are reproducible.
@@ -37,7 +47,6 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 
 from .limbops import LimbLocalOps, LimbOps
 from .mathutil import centered, crt_reconstruct
@@ -144,9 +153,9 @@ def _ksw_gathered(poly, kb, ka, q, psi, ipsi, ninv, *, mesh, data_sharded):
     specs = (P(dspec, "model", None), P(None, "model", None),
              P(None, "model", None), P("model"), P("model", None),
              P("model", None), P("model"))
-    return shard_map(body, mesh=mesh, in_specs=specs,
-                     out_specs=(P(dspec, "model", None),
-                                P(dspec, "model", None)))(
+    return jax.shard_map(body, mesh=mesh, in_specs=specs,
+                         out_specs=(P(dspec, "model", None),
+                                    P(dspec, "model", None)))(
         poly, kb, ka, q, psi, ipsi, ninv)
 
 
@@ -167,13 +176,7 @@ class BFVContext:
         self.limb_q = LimbOps(p.Q, backend=backend, interpret=interpret)
         self.limb_p = LimbOps(p.P, backend=backend, interpret=interpret)
         self.qQ = jnp.asarray(p.Q.q)
-        self.psiQ = jnp.asarray(p.Q.psi_rev)
-        self.ipsiQ = jnp.asarray(p.Q.ipsi_rev)
-        self.ninvQ = jnp.asarray(p.Q.n_inv)
         self.qP = jnp.asarray(p.P.q)
-        self.psiP = jnp.asarray(p.P.psi_rev)
-        self.ipsiP = jnp.asarray(p.P.ipsi_rev)
-        self.ninvP = jnp.asarray(p.P.n_inv)
         self.delta = jnp.asarray(p.delta_mod_q)          # (k,)
         self.qinv_p = jnp.asarray(p.q_inv_mod_p)         # (kp,)
         cqp, cpq = p.conv_q_to_p, p.conv_p_to_q
@@ -184,15 +187,94 @@ class BFVContext:
         self._galois_tabs = {
             g: (jnp.asarray(tab.src), jnp.asarray(tab.sign)) for g, tab in p.galois.items()
         }
-        # jitted primitives (shape-polymorphic: recompiled per batch shape)
-        self._ntt_q = jax.jit(self.limb_q.ntt)
-        self._intt_q = jax.jit(self.limb_q.intt)
+        # jitted primitives; each takes the LimbOps it uses as arguments.
+        # The kernel-bearing ones are compiled for one ciphertext and
+        # mapped over block lanes (_lane_map).
+        self._ntt_j = jax.jit(LimbOps.ntt)
         self._encrypt_j = jax.jit(self._encrypt_impl)
         self._decrypt_j = jax.jit(self._decrypt_impl)
         self._mul_j = jax.jit(self._mul_impl)
         self._mul_tensor_j = jax.jit(self._mul_tensor_impl)
         self._mul_plain_j = jax.jit(self._mul_plain_impl)
-        self._apply_galois_j = jax.jit(self._apply_galois_impl, static_argnums=1)
+        self._rotate_j = jax.jit(self._rotate_impl)
+        self._apply_galois_j = jax.jit(self._apply_galois_impl)
+        self._elementwise_j = jax.jit(self._elementwise_impl,
+                                      static_argnames="op")
+        self._dot_j = jax.jit(self._dot_impl)
+        self.mesh = None        # data mesh the tables are placed on
+        self._spmd: dict = {}   # _lane_program cache
+
+    def _ntt_q(self, x):
+        """Forward NTT over base Q (keygen and benchmarks)."""
+        return self._lane_map(self._ntt_j, (self.limb_q,), (x,), (False,))
+
+    def place_tables(self, mesh) -> None:
+        """Replicate both bases' tables and the Galois gathers over a
+        1-D ("data",) mesh, on which `_lane_map` then runs; None returns
+        them to uncommitted one-device arrays."""
+        if mesh is None:
+            move = lambda a: jnp.asarray(np.asarray(a))
+        else:
+            rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+            move = lambda a: jax.device_put(a, rep)
+        self.limb_q = jax.tree.map(move, self.limb_q)
+        self.limb_p = jax.tree.map(move, self.limb_p)
+        self._galois_tabs = jax.tree.map(move, self._galois_tabs)
+        self.mesh = mesh
+
+    def _lane_map(self, prog, shared, operands, batched):
+        """prog(*shared, *operands) with a one-ciphertext program:
+        operands[i] is a (B, ...) batch where batched[i], else one value
+        every lane shares; results are stacked like the batch."""
+        if self.mesh is not None:
+            return self._lane_map_mesh(prog, shared, operands, batched)
+        if not any(batched):
+            return prog(*shared, *operands)
+        B = next(x for x, b in zip(operands, batched) if b).shape[0]
+        return jnp.stack([
+            prog(*shared, *(x[i] if b else x for x, b in zip(operands, batched)))
+            for i in range(B)])
+
+    def _lane_map_mesh(self, prog, shared, operands, batched):
+        """`_lane_map` on the data mesh: batches split by lane over
+        "data" (replicated when B does not divide it), shared values
+        replicated; each device maps prog over its own lanes."""
+        P = jax.sharding.PartitionSpec
+        mesh, batched = self.mesh, tuple(batched)
+        split = any(batched) and all(x.shape[0] % mesh.shape["data"] == 0
+                                     for x, b in zip(operands, batched) if b)
+        lane = P("data") if split else P()
+        operands = [jax.device_put(x, jax.sharding.NamedSharding(
+            mesh, lane if b else P())) for x, b in zip(operands, batched)]
+        return self._lane_program(prog, len(shared), batched, split)(
+            *shared, *operands)
+
+    def _lane_program(self, prog, nshared: int, batched: tuple, split: bool):
+        """The jitted shard_map program `_lane_map_mesh` runs (cached)."""
+        P = jax.sharding.PartitionSpec
+        key = (prog, self.mesh, nshared, batched, split)
+        if key not in self._spmd:
+            lane = P("data") if split else P()
+
+            def body(*args):
+                sh, ops = args[:nshared], args[nshared:]
+
+                def one(lanes):
+                    it = iter(lanes)
+                    return prog(*sh, *(next(it) if b else x
+                                       for x, b in zip(ops, batched)))
+                lanes = [x for x, b in zip(ops, batched) if b]
+                if not lanes:
+                    return prog(*sh, *ops)
+                if lanes[0].shape[0] == 1:   # lax.map would add ~0.3 GB temp
+                    return one([x[0] for x in lanes])[None]
+                return jax.lax.map(one, lanes)
+
+            self._spmd[key] = jax.jit(jax.shard_map(
+                body, mesh=self.mesh,
+                in_specs=(P(),) * nshared + tuple(lane if b else P() for b in batched),
+                out_specs=lane if any(batched) else P(), check_vma=False))
+        return self._spmd[key]
 
     # --------------------------------------------------------- type glue
     @staticmethod
@@ -290,12 +372,13 @@ class BFVContext:
         u = self._reduce_small(self._sample_ternary())
         e0 = self._reduce_small(self._sample_err())
         e1 = self._reduce_small(self._sample_err())
-        data = self._encrypt_j(jnp.asarray(m_poly), u, e0, e1, pk.b_ntt, pk.a_ntt)
+        data = self._lane_map(
+            self._encrypt_j, (self.limb_q,),
+            (jnp.asarray(m_poly), u, e0, e1, pk.b_ntt, pk.a_ntt), (False,) * 6)
         return Ciphertext(data=data, noise=self.noise_model.fresh(), params=self.params)
 
-    def _encrypt_impl(self, m, u, e0, e1, pkb, pka):
+    def _encrypt_impl(self, lq, m, u, e0, e1, pkb, pka):
         q = self.qQ[:, None]
-        lq = self.limb_q
         u_ntt = lq.ntt(u)
         c0 = (lq.intt(lq.mul(pkb, u_ntt)) + e0 + self.delta[:, None] * m[None, :]) % q
         c1 = (lq.intt(lq.mul(pka, u_ntt)) + e1) % q
@@ -307,40 +390,82 @@ class BFVContext:
     # ------------------------------------------------------------- decrypt
     def decrypt(self, ct, sk: SecretKey) -> jnp.ndarray:
         """Decrypt a Ciphertext -> (n,) or a CiphertextBatch -> (nb, n)."""
-        return self._decrypt_j(ct.data, sk.s_ntt)
+        return self._lane_map(self._decrypt_j, (self.limb_q,),
+                              (ct.data, sk.s_ntt), (ct.data.ndim == 4, False))
 
-    def _decrypt_impl(self, data, s_ntt):
+    def _decrypt_impl(self, lq, data, s_ntt):
         p = self.params
         q = self.qQ[:, None]
-        lq = self.limb_q
         c0, c1 = data[..., 0, :, :], data[..., 1, :, :]
-        x = (c0 + lq.intt(lq.mul(lq.ntt(c1), s_ntt))) % q
+        x = lq.reduce(c0 + lq.intt(lq.mul(lq.ntt(c1), s_ntt)))
         hat_inv, _, _, q_inv_f = self.c_qp
-        y = x * hat_inv[:, None] % q
+        y = lq.reduce(x * hat_inv[:, None])
         yt = y * p.t
         int_part = jnp.sum(yt // q, axis=-2)
         frac = jnp.sum((yt % q).astype(jnp.float64) * q_inv_f[:, None], axis=-2)
         return (int_part + jnp.round(frac).astype(jnp.int64)) % p.t
 
     # ------------------------------------------------------- add/sub/neg
+    def _elementwise_impl(self, lq, x, y, op: str):
+        """The residue arithmetic of the cheap ops, one fused program per
+        (op, shape).  Ciphertext residues are < q < 2^30; plaintext
+        coefficients and scalars are < t < 2^17."""
+        q = self.qQ[:, None]
+        if op == "add":
+            return lq.reduce(x + y)
+        if op == "sub":
+            return lq.reduce(x - y + q)
+        if op == "neg":
+            return lq.reduce(q - x)
+        if op == "mul_scalar":                       # y: scalar c < t
+            return lq.reduce(x * y)
+        if op == "add_plain":                        # y: (n,) poly, or scalar c
+            d = self.delta[:, None] * y if jnp.ndim(y) else (
+                jnp.zeros_like(x[..., 0, :, :]).at[..., 0].set(self.delta * y))
+            c0 = lq.reduce(x[..., 0, :, :] + lq.reduce(d))
+            return x.at[..., 0, :, :].set(c0)
+        raise ValueError(op)
+
+    def _elementwise(self, op: str, x, y):
+        return self._elementwise_j(self.limb_q, x, y, op=op)
+
+    DOT_TERMS = 32      # terms per inner-product program (one compile)
+
+    def _dot_impl(self, lq, acc, datas, cs):
+        # acc < 2^30 plus DOT_TERMS terms < 2^30 * 2^17: below 2^60
+        return lq.reduce(acc + sum(d * cs[i] for i, d in enumerate(datas)))
+
+    def dot_scalars(self, datas: list, coeffs: list):
+        """sum_i coeffs[i] * datas[i] over residues, coefficients in [0, t)
+        (< 2^17), as a few programs of DOT_TERMS terms each (the last
+        padded with zero coefficients), so every length shares one
+        compilation."""
+        g = self.DOT_TERMS
+        acc = jnp.zeros_like(datas[0])
+        coeffs = list(coeffs)
+        for i in range(0, len(datas), g):
+            part = datas[i:i + g]
+            pad = g - len(part)
+            cs = jnp.asarray(coeffs[i:i + g] + [0] * pad, dtype=jnp.int64)
+            acc = self._dot_j(self.limb_q, acc, tuple(part + [part[0]] * pad), cs)
+        return acc
+
     def add(self, a, b):
         out = self._pick(a, b)
-        return self._like(out, (a.data + b.data) % self.qQ[:, None],
+        return self._like(out, self._elementwise("add", a.data, b.data),
                           self.noise_model.add(a.noise, b.noise))
 
     def sub(self, a, b):
         out = self._pick(a, b)
-        return self._like(out, (a.data - b.data) % self.qQ[:, None],
+        return self._like(out, self._elementwise("sub", a.data, b.data),
                           self.noise_model.add(a.noise, b.noise))
 
     def neg(self, a):
-        return self._like(a, (-a.data) % self.qQ[:, None], a.noise)
+        return self._like(a, self._elementwise("neg", a.data, 0), a.noise)
 
     def add_plain(self, a, m_poly: jnp.ndarray):
-        m = jnp.asarray(m_poly)
-        c0 = (a.data[..., 0, :, :] + self.delta[:, None] * m[None, :]) % self.qQ[:, None]
-        return self._like(a, a.data.at[..., 0, :, :].set(c0),
-                          self.noise_model.add(a.noise, a.noise))
+        data = self._elementwise("add_plain", a.data, jnp.asarray(m_poly))
+        return self._like(a, data, self.noise_model.add(a.noise, a.noise))
 
     def sub_from_plain(self, m_poly: jnp.ndarray, a):
         """Encrypted (m - a)."""
@@ -348,14 +473,17 @@ class BFVContext:
 
     # ------------------------------------------------------ plain multiply
     def mul_plain(self, a, m_poly: jnp.ndarray):
-        data = self._mul_plain_j(a.data, jnp.asarray(m_poly))
+        m = jnp.asarray(m_poly)
+        batched = a.data.ndim == 4
+        data = self._lane_map(self._mul_plain_j, (self.limb_q,), (a.data, m),
+                              (batched, batched and m.ndim == 2))
         return self._like(a, data, self.noise_model.mul_plain(a.noise))
 
     # ------------------------------------------------------ scalar constants
     def mul_scalar(self, a, c: int):
         """Multiply by the constant polynomial c — no NTT, tight noise growth."""
         c %= self.params.t
-        data = (a.data * c) % self.qQ[:, None]
+        data = self._elementwise("mul_scalar", a.data, c)
         return self._like(a, data, self.noise_model.mul_scalar(a.noise, c))
 
     def add_scalar(self, a, c: int):
@@ -364,41 +492,38 @@ class BFVContext:
         The batch encoding of the all-c vector is the constant polynomial c,
         so only coefficient 0 of c0 moves (by delta*c per limb)."""
         c %= self.params.t
-        c0 = a.data[..., 0, :, :].at[..., 0].add(self.delta * c) % self.qQ[:, None]
-        return self._like(a, a.data.at[..., 0, :, :].set(c0),
-                          self.noise_model.add(a.noise, a.noise))
+        data = self._elementwise("add_plain", a.data, c)
+        return self._like(a, data, self.noise_model.add(a.noise, a.noise))
 
     def sub_from_scalar(self, c: int, a):
         """Encrypted (c - a) for scalar c."""
         return self.add_scalar(self.neg(a), c)
 
-    def _mul_plain_impl(self, data, m):
-        lq = self.limb_q
-        if m.ndim == 2:
-            # per-block plaintexts: m is (nblocks, n) against a
-            # (nblocks, 2, k, n) batch (fused broadcast_slot extraction)
-            m_ntt = lq.ntt(m[:, None, :] % self.qQ[None, :, None])
-        else:
-            m_ntt = lq.ntt(m[None, :] % self.qQ[:, None])
+    def _mul_plain_impl(self, lq, data, m):
+        """One ciphertext (2, k, n) times one plaintext polynomial (n,)
+        (coefficients < t < q, so already residues in every limb)."""
+        m_ntt = lq.ntt(jnp.broadcast_to(m, (lq.k, m.shape[-1])))
         out0 = lq.intt(lq.mul(lq.ntt(data[..., 0, :, :]), m_ntt))
         out1 = lq.intt(lq.mul(lq.ntt(data[..., 1, :, :]), m_ntt))
         return jnp.stack([out0, out1], axis=-3)
 
     # ------------------------------------------------- HPS base conversion
     @staticmethod
-    def _fbc(x, conv, in_mod, out_mod):
+    def _fbc(x, conv, lin: LimbOps, lout: LimbOps):
         """Exact fast base conversion of the centered value of x.
 
-        x: (..., ka, n) residues mod in_mod; conv: jnp'ed BaseConv tuple;
-        out_mod: (kb,). Products stay < 2^62, exact in int64.
+        x: (..., ka, n) residues of base `lin`; conv: jnp'ed BaseConv
+        tuple; returns (..., kb, n) residues of base `lout`.  Every
+        product is < 2^60 and every sum < 2^36 before its reduction.
         """
         hat_inv, hat_mod_b, a_mod_b, a_inv = conv
-        y = (x * hat_inv[:, None]) % in_mod[:, None]
+        y = lin.reduce(x * hat_inv[:, None])
         v = jnp.round(jnp.sum(y.astype(jnp.float64) * a_inv[:, None], axis=-2)).astype(jnp.int64)
-        terms = (y[..., :, None, :] * hat_mod_b[:, :, None]) % out_mod[None, :, None]
+        terms = lout.reduce(y[..., :, None, :] * hat_mod_b[:, :, None])
         acc = jnp.sum(terms, axis=-3)                      # (..., kb, n) < ka * b_j
-        out = (acc - v[..., None, :] * a_mod_b[:, None]) % out_mod[:, None]
-        return out
+        ka = hat_inv.shape[0]                              # v <= ka: shift by ka*b_j
+        return lout.reduce(acc - v[..., None, :] * a_mod_b[:, None]
+                           + ka * lout.q[:, None])
 
     # ------------------------------------------------------- ct-ct multiply
     def mul(self, a, b, rlk: KSwitchKey, mesh=None):
@@ -407,9 +532,12 @@ class BFVContext:
         mesh "model" axis (engine/sharded.py) — byte-identical output,
         different collective structure."""
         if mesh is None:
-            data = self._mul_j(a.data, b.data, rlk.b, rlk.a)
+            data = self._lane_map(
+                self._mul_j, (self.limb_q, self.limb_p, rlk.b, rlk.a),
+                (a.data, b.data), (a.data.ndim == 4, b.data.ndim == 4))
         else:
-            r0, r1, r2 = self._mul_tensor_j(a.data, b.data)
+            r0, r1, r2 = self._mul_tensor_j(self.limb_q, self.limb_p,
+                                            a.data, b.data)
             ks0, ks1 = self.kswitch_gathered(r2, rlk, mesh)
             q = self.qQ[:, None]
             data = jnp.stack([(r0 + ks0) % q, (r1 + ks1) % q], axis=-3)
@@ -417,17 +545,16 @@ class BFVContext:
         return self._like(self._pick(a, b), data,
                           nz.keyswitch(nz.mul(a.noise, b.noise)))
 
-    def _mul_tensor_impl(self, da, db):
+    def _mul_tensor_impl(self, lq, lp, da, db):
         """Steps 1-4 of the HPS multiply: the degree-2 tensor scaled back
         to base Q, before relinearization."""
         p = self.params
         qQ, qP = self.qQ, self.qP
-        lq, lp = self.limb_q, self.limb_p
         a0, a1 = da[..., 0, :, :], da[..., 1, :, :]
         b0, b1 = db[..., 0, :, :], db[..., 1, :, :]
         # 1. lift to Q ∪ P
-        aP = (self._fbc(a0, self.c_qp, qQ, qP), self._fbc(a1, self.c_qp, qQ, qP))
-        bP = (self._fbc(b0, self.c_qp, qQ, qP), self._fbc(b1, self.c_qp, qQ, qP))
+        aP = (self._fbc(a0, self.c_qp, lq, lp), self._fbc(a1, self.c_qp, lq, lp))
+        bP = (self._fbc(b0, self.c_qp, lq, lp), self._fbc(b1, self.c_qp, lq, lp))
         # 2. NTT + tensor in both bases
         fa = [lq.ntt(a0), lq.ntt(a1)]
         fb = [lq.ntt(b0), lq.ntt(b1)]
@@ -446,31 +573,31 @@ class BFVContext:
         # 3. scale by t/Q exactly: r = (t*E - [tE]_Q) / Q, computed in base P
         rs = []
         for eq, ep in zip(tq, tp):
-            rem_q = (eq * p.t) % qQ[:, None]
-            rem_p = self._fbc(rem_q, self.c_qp, qQ, qP)
-            r_p = ((ep * p.t - rem_p) % qP[:, None]) * self.qinv_p[:, None] % qP[:, None]
-            rs.append(self._fbc(r_p, self.c_pq, qP, qQ))       # 4. back to base Q
+            rem_q = lq.reduce(eq * p.t)
+            rem_p = self._fbc(rem_q, self.c_qp, lq, lp)
+            r_p = lp.reduce(lp.reduce(ep * p.t - rem_p + qP[:, None])
+                            * self.qinv_p[:, None])
+            rs.append(self._fbc(r_p, self.c_pq, lp, lq))       # 4. back to base Q
         return rs[0], rs[1], rs[2]
 
-    def _mul_impl(self, da, db, rlk_b, rlk_a):
-        r0, r1, r2 = self._mul_tensor_impl(da, db)
+    def _mul_impl(self, lq, lp, rlk_b, rlk_a, da, db):
+        r0, r1, r2 = self._mul_tensor_impl(lq, lp, da, db)
         # 5. relinearize r2
-        ks0, ks1 = self._kswitch_inner(r2, rlk_b, rlk_a)
-        q = self.qQ[:, None]
-        return jnp.stack([(r0 + ks0) % q, (r1 + ks1) % q], axis=-3)
+        ks0, ks1 = self._kswitch_inner(lq, r2, rlk_b, rlk_a)
+        return jnp.stack([lq.reduce(r0 + ks0), lq.reduce(r1 + ks1)], axis=-3)
 
     # --------------------------------------------------------- key switch
-    def _kswitch_inner(self, poly, ksk_b, ksk_a):
+    def _kswitch_inner(self, lq, poly, ksk_b, ksk_a):
         """Key-switch `poly` (coeff domain, (..., k, n)): coeff-domain pair."""
         q = self.qQ[:, None]
         qvec = self.qQ
         half = qvec // 2
-        lq = self.limb_q
         cent = poly - qvec[:, None] * (poly > half[:, None])       # centered digits
-        digits = cent[..., :, None, :] % qvec[None, :, None]       # (..., kd, k, n)
+        # digit i mod q_j: cent_i + q_j is in (0, 2^31) for 30-bit primes
+        digits = lq.reduce(cent[..., :, None, :] + q)              # (..., kd, k, n)
         d_ntt = lq.ntt(digits)
-        acc_b = jnp.sum(lq.mul(d_ntt, ksk_b), axis=-3) % q
-        acc_a = jnp.sum(lq.mul(d_ntt, ksk_a), axis=-3) % q
+        acc_b = lq.reduce(jnp.sum(lq.mul(d_ntt, ksk_b), axis=-3))
+        acc_a = lq.reduce(jnp.sum(lq.mul(d_ntt, ksk_a), axis=-3))
         return lq.intt(acc_b), lq.intt(acc_a)
 
     def kswitch_gathered(self, poly, ksk: KSwitchKey, mesh):
@@ -491,25 +618,36 @@ class BFVContext:
         p3 = poly.reshape((B,) + poly.shape[-2:])
         data_ax = mesh.shape.get("data", 1)
         data_sharded = B > 1 and B % data_ax == 0
-        b, a = _ksw_gathered(p3, ksk.b, ksk.a, self.qQ, self.psiQ,
-                             self.ipsiQ, self.ninvQ, mesh=mesh,
+        tabs = self.limb_q.arrays
+        b, a = _ksw_gathered(p3, ksk.b, ksk.a, self.qQ, tabs["psi"],
+                             tabs["ipsi"], tabs["ninv"], mesh=mesh,
                              data_sharded=data_sharded)
         return b.reshape(poly.shape), a.reshape(poly.shape)
 
     # ------------------------------------------------------------ rotation
-    def _apply_galois_impl(self, data, g: int):
-        src, sign = self._galois_tabs[g]
+    def _apply_galois_impl(self, data, src, sign):
         return (sign * data[..., src]) % self.qQ[:, None]
 
+    def _rotate_impl(self, lq, ksk_b, ksk_a, src, sign, data):
+        """sigma_g (as its gather table) then key-switch back to s: the
+        whole rotation, one program for every Galois element."""
+        q = self.qQ[:, None]
+        rot = lq.reduce(sign * data[..., src] + q)
+        ks0, ks1 = self._kswitch_inner(lq, rot[..., 1, :, :], ksk_b, ksk_a)
+        return jnp.stack([lq.reduce(rot[..., 0, :, :] + ks0), ks1], axis=-3)
+
     def apply_galois(self, ct, g: int, gk: KSwitchKey, mesh=None):
-        rot = self._apply_galois_j(ct.data, g)
         if mesh is None:
-            ks0, ks1 = self._kswitch_inner(rot[..., 1, :, :], gk.b, gk.a)
+            src, sign = self._galois_tabs[g]
+            data = self._lane_map(self._rotate_j,
+                                  (self.limb_q, gk.b, gk.a, src, sign),
+                                  (ct.data,), (ct.data.ndim == 4,))
         else:
+            rot = self._apply_galois_j(ct.data, *self._galois_tabs[g])
             ks0, ks1 = self.kswitch_gathered(rot[..., 1, :, :], gk, mesh)
-        c0 = (rot[..., 0, :, :] + ks0) % self.qQ[:, None]
-        return self._like(ct, jnp.stack([c0, ks1], axis=-3),
-                          self.noise_model.rotate(ct.noise))
+            c0 = (rot[..., 0, :, :] + ks0) % self.qQ[:, None]
+            data = jnp.stack([c0, ks1], axis=-3)
+        return self._like(ct, data, self.noise_model.rotate(ct.noise))
 
     def rotate_rows(self, ct, step: int, gks: dict[int, KSwitchKey],
                     mesh=None):
@@ -588,7 +726,7 @@ class BFVContext:
         q = self.qQ[:, None]
         lq = self.limb_q
         x = np.asarray((ct.data[0] + lq.intt(lq.mul(lq.ntt(ct.data[1]), sk.s_ntt))) % q)
-        m = np.asarray(self._decrypt_j(ct.data, sk.s_ntt))
+        m = np.asarray(self.decrypt(ct, sk))
         Q = p.bigQ()
         tQ = p.t * Q
         worst = 1

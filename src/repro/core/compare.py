@@ -30,6 +30,7 @@ slack noted in §5.3 ("inequality checks ... lookup table accesses (BSGS)").
 """
 from __future__ import annotations
 
+import math
 import os
 from functools import lru_cache
 
@@ -59,7 +60,8 @@ def sgn_odd_coeffs(p: int) -> np.ndarray:
     """s[j] = coefficient of z^(2j+1) in the interpolant of sgn over Z_p.
 
     Returned as int64 array of length (p-1)//2 (degree p-2 polynomial).
-    Cached to disk: the p=65537 table costs ~2^30 modmuls to build.
+    Cached to disk in `_coeff_cache/` (git-ignored, built on first use):
+    the p=65537 table costs ~2^30 modmuls to build.
     """
     path = os.path.join(_CACHE_DIR, f"sgn_{p}.npy")
     if os.path.exists(path):
@@ -76,7 +78,10 @@ def sgn_odd_coeffs(p: int) -> np.ndarray:
         if j + 1 < half:
             v = v * ainv2 % p
     os.makedirs(_CACHE_DIR, exist_ok=True)
-    np.save(path, s)
+    tmp = f"{path}.{os.getpid()}.tmp"           # concurrent writers never
+    with open(tmp, "wb") as f:                  # expose a half-written file
+        np.save(f, s)
+    os.replace(tmp, path)
     return s
 
 
@@ -198,6 +203,14 @@ class _PSEvaluator:
             return lo
         hi = ops.mul(hi, self.pow2(m))
         return hi if lo is None else ops.add(lo, hi)
+
+
+def live_ciphertexts(circuit: str, t: int) -> int:
+    """Ciphertexts one lane of a comparison circuit keeps alive at once,
+    rounded up: an LT ('lt') holds its Paterson-Stockmeyer baby and giant
+    powers (fewer than 2*sqrt(t), `_PSEvaluator`) plus products in
+    flight, an EQ ('eq') the short square chain of `pow_ct`."""
+    return 32 if circuit == "eq" else 2 * math.isqrt(t) + 64
 
 
 def lt_zero(ops, z):

@@ -6,7 +6,6 @@ rows by r and 2n-1 swaps rows (see params._make_slot_map).
 """
 from __future__ import annotations
 
-import jax.numpy as jnp
 import numpy as np
 
 from . import ntt as nttm
@@ -14,44 +13,49 @@ from .params import HEParams
 
 
 class BatchEncoder:
+    """Encoding is plaintext work on the client's side of the trust
+    boundary: it runs in numpy on the host (t < 2^17 keeps every
+    product exact in int64), and only the encoded polynomial goes to
+    the device."""
+
     def __init__(self, params: HEParams):
         self.params = params
         T = params.T
-        self.qt = jnp.asarray(T.q)
-        self.psi = jnp.asarray(T.psi_rev)
-        self.ipsi = jnp.asarray(T.ipsi_rev)
-        self.ninv = jnp.asarray(T.n_inv)
-        self.slot_to_coeff = jnp.asarray(params.slot_to_coeff)
+        self.qt = np.asarray(T.q)
+        self.psi = np.asarray(T.psi_rev)
+        self.ipsi = np.asarray(T.ipsi_rev)
+        self.ninv = np.asarray(T.n_inv)
+        self.slot_to_coeff = np.asarray(params.slot_to_coeff)
         # inverse permutation: coeff index -> slot
-        inv = np.zeros(params.n, dtype=np.int32)
-        inv[np.asarray(params.slot_to_coeff)] = np.arange(params.n)
-        self.coeff_to_slot = jnp.asarray(inv)
+        self.coeff_to_slot = np.zeros(params.n, dtype=np.int32)
+        self.coeff_to_slot[self.slot_to_coeff] = np.arange(params.n)
 
-    def encode(self, values) -> jnp.ndarray:
+    def encode(self, values) -> np.ndarray:
         """values: up to n ints (taken mod t); returns plaintext poly (n,)."""
         p = self.params
-        vals = jnp.asarray(values, dtype=jnp.int64) % p.t
-        if vals.shape[0] < p.n:
-            vals = jnp.concatenate([vals, jnp.zeros(p.n - vals.shape[0], dtype=jnp.int64)])
+        vals = np.zeros(p.n, dtype=np.int64)
+        v = np.asarray(values, dtype=np.int64) % p.t
+        vals[: v.shape[0]] = v
         evals = vals[self.coeff_to_slot][None, :]
-        poly = nttm.intt_ref(evals, self.ipsi, self.ninv, self.qt)
-        return poly[0]
+        return nttm.intt_ref(evals, self.ipsi, self.ninv, self.qt)[0]
 
-    def decode(self, poly: jnp.ndarray) -> jnp.ndarray:
-        evals = nttm.ntt_ref(poly[None, :], self.psi, self.qt)[0]
+    def decode(self, poly) -> np.ndarray:
+        evals = nttm.ntt_ref(np.asarray(poly, dtype=np.int64)[None, :],
+                             self.psi, self.qt)[0]
         return evals[self.slot_to_coeff]
 
-    def decode_signed(self, poly: jnp.ndarray) -> jnp.ndarray:
+    def decode_signed(self, poly) -> np.ndarray:
         """Decode with centered representatives in (-t/2, t/2]."""
         v = self.decode(poly)
         t = self.params.t
         return v - t * (v > t // 2)
 
     # Common mask plaintexts -------------------------------------------------
-    def constant(self, c: int) -> jnp.ndarray:
-        return self.encode(jnp.full(self.params.n, c, dtype=jnp.int64))
+    def constant(self, c: int) -> np.ndarray:
+        return self.encode(np.full(self.params.n, c, dtype=np.int64))
 
-    def basis(self, slot: int) -> jnp.ndarray:
+    def basis(self, slot: int) -> np.ndarray:
         """All-zeros except a single 1 at `slot` (the paper's Extract mask)."""
-        v = jnp.zeros(self.params.n, dtype=jnp.int64).at[slot].set(1)
+        v = np.zeros(self.params.n, dtype=np.int64)
+        v[slot] = 1
         return self.encode(v)

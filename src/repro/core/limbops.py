@@ -7,25 +7,23 @@ forward/inverse negacyclic NTT — either through the Pallas kernels
 oracles, selected by a backend flag:
 
     "ref"     exact int64 jnp arithmetic (always available)
-    "pallas"  uint32 Barrett/Shoup kernels; interpret mode on CPU,
+    "pallas"  uint32 Barrett/Shoup kernels; interpret mode off-TPU,
               compiled on TPU
     "auto"    "pallas" when running on a TPU, "ref" otherwise
 
 The default comes from the NSHEDB_LIMB_BACKEND environment variable
-("auto" if unset).  The Barrett path is tuned for primes in
-(2^28, 2^30); bases outside that window (e.g. the 31-bit HPS auxiliary
-base P) silently fall back to "ref" so a single flag can govern a whole
-parameter set.
+("auto" if unset).  The Barrett path needs every prime inside
+(2^28, 2^30); both bases of every parameter set (core/params.py) sit
+there, and asking for "pallas" on a base outside it raises.
 
 Every entry point accepts arrays of shape (..., k, n) — any number of
 leading batch axes over the (limb, coefficient) layout — and is safe to
 call from inside jit.  Batches are flattened to the (rows, n) layout the
-kernels grid over, with the per-limb twiddle/modulus tables tiled to
-match, so a whole column of ciphertext blocks runs as one kernel launch.
+kernels grid over, so a whole column of ciphertext blocks runs as one
+kernel launch.
 """
 from __future__ import annotations
 
-import contextlib
 import os
 
 import jax
@@ -34,9 +32,11 @@ import numpy as np
 
 from . import ntt as nttm
 from .params import NttTables
-from ..kernels.u32 import barrett_precompute
+from ..kernels import resolve_interpret
+from ..kernels.u32 import barrett_precompute, barrett_reduce
 from ..kernels.modops.modops import add_mod_pallas, mul_mod_pallas, sub_mod_pallas
 from ..kernels.ntt.ntt import ntt_fwd_pallas, ntt_inv_pallas
+from ..kernels.ntt.ops import kernel_tables
 
 BACKENDS = ("ref", "pallas", "auto")
 
@@ -48,43 +48,24 @@ def default_backend() -> str:
     return os.environ.get("NSHEDB_LIMB_BACKEND", "auto")
 
 
-# Depth of nested force_ref() contexts.  While > 0, every LimbOps call
-# takes the jnp reference path regardless of the instance's backend flag.
-_FORCE_REF = 0
-
-
-@contextlib.contextmanager
-def force_ref():
-    """Route all limb primitives through the jnp reference path.
-
-    shard_map bodies cannot host a Pallas interpret-mode launch (the
-    interpreter's host callbacks do not trace under the per-shard
-    closed-over mesh), so the sharded executor wraps shard-local
-    evaluation in this context.  The flag is consulted at trace time:
-    a function traced inside the context bakes in the ref path.
-    """
-    global _FORCE_REF
-    _FORCE_REF += 1
-    try:
-        yield
-    finally:
-        _FORCE_REF -= 1
-
-
 def pallas_supported(primes) -> bool:
     """True iff every modulus sits in the uint32 Barrett window."""
     return all(_Q_MIN < int(q) < _Q_MAX for q in primes)
 
 
 def resolve_backend(backend: str | None, primes) -> str:
-    """Normalize a user flag to the backend that will actually run."""
+    """Normalize a user flag to the backend that will run; raises when
+    the kernels are asked for (or chosen on a TPU) but a modulus lies
+    outside their Barrett window."""
     b = backend or default_backend()
     if b not in BACKENDS:
         raise ValueError(f"unknown limb backend {b!r}; expected one of {BACKENDS}")
     if b == "auto":
         b = "pallas" if jax.default_backend() == "tpu" else "ref"
     if b == "pallas" and not pallas_supported(primes):
-        b = "ref"
+        raise ValueError(
+            f"limb backend 'pallas' needs every modulus in (2^28, 2^30); "
+            f"got {[int(q).bit_length() for q in primes]}-bit primes")
     return b
 
 
@@ -97,8 +78,8 @@ class LimbLocalOps:
     NTT primitives are plain limb-major math over (..., kL, n) — zero
     communication (the all-gather of key-switch digits happens *before*
     these run; see core/bfv.py: kswitch_gathered).  Always ref-backed:
-    Pallas interpret mode cannot trace inside shard_map, and the ref
-    path is bit-identical anyway.
+    the limb axis has no chip path yet (k = 30 has no real placement on
+    four chips), and the ref path is bit-identical.
     """
 
     def __init__(self, q, psi, ipsi, ninv):
@@ -130,38 +111,49 @@ class LimbLocalOps:
                              self._tile(self.q, B)).reshape(a.shape)
 
 
+@jax.tree_util.register_pytree_node_class
 class LimbOps:
-    """Pointwise + NTT primitives for one RNS base, kernel- or ref-backed."""
+    """Pointwise + NTT primitives for one RNS base, kernel- or ref-backed.
+
+    A pytree whose leaves are the base's device tables: jitted callers
+    take the instance as an argument (core/bfv.py), so the tables reach
+    the compiled program as buffers instead of embedded constants.
+    """
 
     def __init__(self, tables: NttTables, backend: str | None = None,
                  interpret: bool | None = None):
+        primes = tuple(int(q) for q in tables.primes)
+        backend = resolve_backend(backend, primes)
+        arrays = {"q": jnp.asarray(tables.q),
+                  "psi": jnp.asarray(tables.psi_rev),
+                  "ipsi": jnp.asarray(tables.ipsi_rev),
+                  "ninv": jnp.asarray(tables.n_inv)}
+        if backend == "pallas":
+            arrays["q_col"] = jnp.asarray(np.asarray(primes, dtype=np.uint32)[:, None])
+            arrays["mu_col"] = jnp.asarray(np.array(
+                [barrett_precompute(q) for q in primes], dtype=np.uint32)[:, None])
+            arrays["fwd"] = kernel_tables(tables)
+            arrays["inv"] = kernel_tables(tables, inverse=True)
+        self._bind(tables, backend, resolve_interpret(interpret), arrays)
+
+    def _bind(self, tables, backend, interpret, arrays):
         self.tables = tables
         self.primes = tuple(int(q) for q in tables.primes)
         self.k = len(self.primes)
         self.n = tables.psi_rev.shape[1]
-        self.backend = resolve_backend(backend, self.primes)
-        self.interpret = (jax.default_backend() != "tpu"
-                          if interpret is None else interpret)
-        # ref tables (int64)
-        self.q = jnp.asarray(tables.q)
-        self.psi = jnp.asarray(tables.psi_rev)
-        self.ipsi = jnp.asarray(tables.ipsi_rev)
-        self.ninv = jnp.asarray(tables.n_inv)
-        if self.backend == "pallas":
-            q64 = np.asarray(tables.q, dtype=np.uint64)
-            self._q_u32 = jnp.asarray(q64.astype(np.uint32))
-            self._mu_u32 = jnp.asarray(
-                np.array([barrett_precompute(q) for q in self.primes],
-                         dtype=np.uint32))
-            psi = np.asarray(tables.psi_rev, dtype=np.uint64)
-            ipsi = np.asarray(tables.ipsi_rev, dtype=np.uint64)
-            ninv = np.asarray(tables.n_inv, dtype=np.uint64)
-            self._psi_u32 = jnp.asarray(psi.astype(np.uint32))
-            self._psi_shoup = jnp.asarray(((psi << np.uint64(32)) // q64[:, None]).astype(np.uint32))
-            self._ipsi_u32 = jnp.asarray(ipsi.astype(np.uint32))
-            self._ipsi_shoup = jnp.asarray(((ipsi << np.uint64(32)) // q64[:, None]).astype(np.uint32))
-            self._ninv_u32 = jnp.asarray(ninv.astype(np.uint32))
-            self._ninv_shoup = jnp.asarray(((ninv << np.uint64(32)) // q64).astype(np.uint32))
+        self.backend = backend
+        self.interpret = interpret
+        self.arrays = arrays
+        self.q = arrays["q"]
+
+    def tree_flatten(self):
+        return (self.arrays,), (self.tables, self.backend, self.interpret)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        obj = object.__new__(cls)
+        obj._bind(*aux, children[0])
+        return obj
 
     # --------------------------------------------------------- shape glue
     def _rows(self, a):
@@ -177,7 +169,7 @@ class LimbOps:
         return jnp.concatenate([tab] * B, axis=0) if B > 1 else tab
 
     def _use_ref(self) -> bool:
-        return self.backend == "ref" or _FORCE_REF > 0
+        return self.backend == "ref"
 
     # ----------------------------------------------------- pointwise ops
     def _pointwise(self, a, b, kern_fn, ref_fn):
@@ -188,31 +180,39 @@ class LimbOps:
             return ref_fn(a.reshape(-1, self.n), b.reshape(-1, self.n)).reshape(shape)
         ar, B = self._rows(a)
         br, _ = self._rows(b)
-        out = kern_fn(ar.astype(jnp.uint32), br.astype(jnp.uint32), B)
+        out = kern_fn(ar.astype(jnp.uint32), br.astype(jnp.uint32),
+                      self._tile(self.arrays["q_col"], B))
         return out.astype(jnp.int64).reshape(shape)
 
     def mul(self, a, b):
         """Pointwise a*b mod q over (..., k, n); exact, result in [0, q)."""
         return self._pointwise(
             a, b,
-            lambda x, y, B: mul_mod_pallas(
-                x, y, self._tile(self._q_u32[:, None], B),
-                self._tile(self._mu_u32[:, None], B), interpret=self.interpret),
+            lambda x, y, q: mul_mod_pallas(
+                x, y, q, self._tile(self.arrays["mu_col"], x.shape[0] // self.k),
+                interpret=self.interpret),
             lambda x, y: (x * y) % self._row_q(x))
 
     def add(self, a, b):
         return self._pointwise(
             a, b,
-            lambda x, y, B: add_mod_pallas(
-                x, y, self._tile(self._q_u32[:, None], B), interpret=self.interpret),
+            lambda x, y, q: add_mod_pallas(x, y, q, interpret=self.interpret),
             lambda x, y: (x + y) % self._row_q(x))
 
     def sub(self, a, b):
         return self._pointwise(
             a, b,
-            lambda x, y, B: sub_mod_pallas(
-                x, y, self._tile(self._q_u32[:, None], B), interpret=self.interpret),
+            lambda x, y, q: sub_mod_pallas(x, y, q, interpret=self.interpret),
             lambda x, y: (x - y) % self._row_q(x))
+
+    def reduce(self, x):
+        """x mod q over (..., k, n) for int64 x in [0, 2^60): the uint32
+        Barrett reduction on the kernel backend (a 64-bit remainder is a
+        long software division on a TPU), int64 `%` on the reference."""
+        if self.backend == "ref":
+            return x % self.q[:, None]
+        return barrett_reduce(x, self.arrays["q_col"],
+                              self.arrays["mu_col"]).astype(jnp.int64)
 
     def _row_q(self, rows):
         """(B*k,) -> (B*k, 1) modulus column for flattened-row ref math."""
@@ -225,12 +225,11 @@ class LimbOps:
         shape = a.shape
         ar, B = self._rows(a)
         if self._use_ref():
-            out = nttm.ntt_ref(ar, self._tile(self.psi, B), self._tile(self.q, B))
+            out = nttm.ntt_ref(ar, self._tile(self.arrays["psi"], B),
+                               self._tile(self.q, B))
         else:
-            out = ntt_fwd_pallas(
-                ar.astype(jnp.uint32), self._tile(self._psi_u32, B),
-                self._tile(self._psi_shoup, B), self._tile(self._q_u32[:, None], B),
-                interpret=self.interpret).astype(jnp.int64)
+            out = ntt_fwd_pallas(ar.astype(jnp.uint32), *self.arrays["fwd"],
+                                 interpret=self.interpret).astype(jnp.int64)
         return out.reshape(shape)
 
     def intt(self, a):
@@ -238,13 +237,10 @@ class LimbOps:
         shape = a.shape
         ar, B = self._rows(a)
         if self._use_ref():
-            out = nttm.intt_ref(ar, self._tile(self.ipsi, B),
-                                self._tile(self.ninv, B), self._tile(self.q, B))
+            out = nttm.intt_ref(ar, self._tile(self.arrays["ipsi"], B),
+                                self._tile(self.arrays["ninv"], B),
+                                self._tile(self.q, B))
         else:
-            out = ntt_inv_pallas(
-                ar.astype(jnp.uint32), self._tile(self._ipsi_u32, B),
-                self._tile(self._ipsi_shoup, B), self._tile(self._q_u32[:, None], B),
-                self._tile(self._ninv_u32[:, None], B),
-                self._tile(self._ninv_shoup[:, None], B),
-                interpret=self.interpret).astype(jnp.int64)
+            out = ntt_inv_pallas(ar.astype(jnp.uint32), *self.arrays["inv"],
+                                 interpret=self.interpret).astype(jnp.int64)
         return out.reshape(shape)

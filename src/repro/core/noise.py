@@ -61,7 +61,12 @@ class NoiseProfile:
 
 
 def paper_profile() -> NoiseProfile:
-    """The paper's SEAL set: n=32768, log Q = 881, t = 65537."""
+    """The paper's set: n=32768, t=65537, k=30 limbs of 30-bit primes.
+
+    log Q is 899.5 bits (measured on `paper_params()`), not the 881 bits
+    of the paper's SEAL set — 881 is the HE-standard bound for 128-bit
+    security at n=32768, which this set exceeds by 18.5 bits.
+    """
     return NoiseProfile(n=32768, t=65537, k=30)
 
 

@@ -7,17 +7,26 @@ and produces the evaluation vector in bit-reversed order; the inverse uses
 Gentleman-Sande butterflies and consumes that order, so pointwise products
 round-trip without explicit bit-reversal passes.
 
-This module is (a) the execution path on CPU and (b) the oracle for the
-Pallas kernel in kernels/ntt. Products are <= (2^30-1)^2 < 2^63: exact in
-int64.
+This module is (a) the execution path on CPU, (b) the oracle for the
+Pallas kernel in kernels/ntt and (c) the plaintext-modulus transform of
+the batch encoder.  Products are <= (2^30-1)^2 < 2^63: exact in int64.
+Given numpy arrays the transforms run in numpy on the host (the encoder
+and parameter setup do this: as an int64 XLA program for a TPU the
+transform takes many minutes to compile), given jax arrays in jnp.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
+
+
+def _xp(a):
+    return np if isinstance(a, np.ndarray) else jnp
 
 
 def ntt_ref(a, psi_rev, q):
     """Forward negacyclic NTT. a: (k, n); psi_rev: (k, n); q: (k,)."""
+    xp = _xp(a)
     k, n = a.shape
     qc = q[:, None, None]
     log_n = n.bit_length() - 1
@@ -28,12 +37,13 @@ def ntt_ref(a, psi_rev, q):
         S = psi_rev[:, m : 2 * m]  # (k, m)
         U = a[:, :, 0, :]
         V = (a[:, :, 1, :] * S[:, :, None]) % qc
-        a = jnp.stack([(U + V) % qc, (U - V) % qc], axis=2)
+        a = xp.stack([(U + V) % qc, (U - V) % qc], axis=2)
     return a.reshape(k, n)
 
 
 def intt_ref(a, ipsi_rev, n_inv, q):
     """Inverse negacyclic NTT (consumes bit-reversed evaluation order)."""
+    xp = _xp(a)
     k, n = a.shape
     qc = q[:, None, None]
     log_n = n.bit_length() - 1
@@ -44,7 +54,7 @@ def intt_ref(a, ipsi_rev, n_inv, q):
         S = ipsi_rev[:, h : 2 * h]  # (k, h)
         U = a[:, :, 0, :]
         V = a[:, :, 1, :]
-        a = jnp.stack([(U + V) % qc, ((U - V) * S[:, :, None]) % qc], axis=2)
+        a = xp.stack([(U + V) % qc, ((U - V) * S[:, :, None]) % qc], axis=2)
     a = a.reshape(k, n)
     return (a * n_inv[:, None]) % q[:, None]
 

@@ -8,8 +8,13 @@ limb-arithmetic inside Pallas kernels (see kernels/modops).
 
 Bases:
   Q  — the ciphertext base (k limbs).
-  P  — the auxiliary base used by HPS RNS multiplication (k+1 limbs),
-       P > n * Q / 2 guarantees the tensor product never wraps in Q∪P.
+  P  — the auxiliary base used by HPS RNS multiplication (k+2 limbs).
+       The scaled tensor r = (t*E - [t*E]_Q) / Q is computed in base P;
+       with centered inputs |E| <= n * Q^2 / 2, so |r| <= n * t * Q / 2
+       and P > n * t * Q keeps r exact in the worst case (no
+       statistical margin).  Its primes are the next 30-bit NTT primes
+       below Q's, so both bases sit inside the kernels' Barrett window
+       (2^28, 2^30).
 
 All tables are numpy/JAX arrays computed once per parameter set with exact
 Python integer arithmetic (mathutil.py).
@@ -216,9 +221,7 @@ def _make_slot_map(n: int, t: int, T: NttTables) -> np.ndarray:
 
     x_poly = np.zeros((1, n), dtype=np.int64)
     x_poly[0, 1] = 1
-    evals = np.asarray(
-        nttmod.ntt_ref(x_poly, T.psi_rev[:1], T.q[:1])
-    )[0]
+    evals = nttmod.ntt_ref(x_poly, T.psi_rev[:1], T.q[:1])[0]   # numpy, host
     psi_t = root_of_unity(2 * n, t)
     dlog = _discrete_log_table(psi_t, t, 2 * n)
     e_of_k = np.array([dlog[int(v)] for v in evals])
@@ -244,7 +247,7 @@ def make_params(n: int = 4096, t: int = 65537, k: int = 6, qbits: int = 30) -> H
     assert n & (n - 1) == 0, "n must be a power of two"
     assert (t - 1) % (2 * n) == 0, f"batching needs 2n | t-1 (t={t}, n={n})"
     q_primes = find_ntt_primes(n, qbits, k, avoid=(t,))
-    p_primes = find_ntt_primes(n, qbits + 1, k + 1, avoid=tuple(q_primes) + (t,))
+    p_primes = find_ntt_primes(n, qbits, k + 2, avoid=tuple(q_primes) + (t,))
 
     Q = _make_ntt_tables(q_primes, n)
     P = _make_ntt_tables(p_primes, n)
@@ -256,7 +259,7 @@ def make_params(n: int = 4096, t: int = 65537, k: int = 6, qbits: int = 30) -> H
     bigP = 1
     for p in p_primes:
         bigP *= p
-    assert bigP > n * bigQ // 2, "aux base too small for HPS tensor product"
+    assert bigP > n * t * bigQ, "aux base too small for HPS scaling"
 
     delta = bigQ // t
     delta_mod_q = np.array([delta % q for q in q_primes], dtype=np.int64)
@@ -309,10 +312,11 @@ def small_params() -> HEParams:
 
 
 def paper_params() -> HEParams:
-    """The paper's production set: n=32768, t=65537, log Q ~ 881.
+    """The paper's production set: n=32768, t=65537, k=30.
 
-    30 limbs x ~29.4 effective bits ~ 884 bits — the HE-standard row the
-    paper cites (n=32768 admits log Q up to 881 at 128-bit security; we
-    match it to within one limb's rounding).
+    30 limbs of 30-bit primes give log Q = 899.5 bits (measured).  The
+    HE-standard row the paper cites admits at most 881 bits at n=32768
+    for 128-bit security, so this set sits 18.5 bits above that bound
+    (ROADMAP §2 "Reach", item 1).
     """
     return make_params(n=32768, t=65537, k=30)

@@ -49,8 +49,10 @@ import dataclasses
 import math
 from typing import Any
 
+import jax
 import numpy as np
 
+from ..core import compare
 from ..core.bfv import BFVContext, Ciphertext, CiphertextBatch, Keys
 from ..runtime import faults
 from ..core.encoder import BatchEncoder
@@ -215,6 +217,11 @@ class _BackendBase:
         noise = ct.noise if hasattr(ct, "noise") else ct
         return self.model.levels_left(noise)
 
+    def circuit_lanes(self, kind: str) -> int | None:
+        """Most block lanes one stacked comparison circuit may hold, or
+        None when nothing bounds it."""
+        return None
+
     def ensure_levels(self, ct, levels: int):
         """Planned refresh (§2.1.1 'selectively apply bootstrapping'): if
         the ciphertext cannot absorb `levels` more multiplications, refresh
@@ -262,6 +269,14 @@ class _BackendBase:
 # Real-ciphertext backend.
 # ---------------------------------------------------------------------------
 
+def lanes_within(free_bytes: int, circuit: str, t: int, params) -> int:
+    """Lanes (at least one) of a stacked comparison circuit whose live
+    ciphertexts (compare.live_ciphertexts, each (2, k, n) int64) fit
+    `free_bytes`."""
+    live = compare.live_ciphertexts(circuit, t)
+    return max(1, free_bytes // (live * 2 * params.k * params.n * 8))
+
+
 class BFVBackend(_BackendBase):
     def __init__(self, params: HEParams, seed: int = 0,
                  kernel_backend: str | None = None, interpret: bool | None = None):
@@ -276,12 +291,57 @@ class BFVBackend(_BackendBase):
         self.model = self.ctx.noise_model
         self.limbs = params.k          # RNS tower height (model-axis extent)
         self._depth: dict[int, int] = {}
+        self._home_mesh = None         # mesh keys and tables are replicated on
 
     def _limb_mesh(self):
         """The active context's 2-D mesh iff key-switches should
         all-gather over a real model axis (engine/sharded.py)."""
         ctx = self.shard_ctx
         return ctx.limb_mesh if ctx is not None else None
+
+    def _home(self) -> None:
+        """Place the switching keys and limb tables for the next op.
+
+        Under a 1-D data mesh (engine/sharded.py) every device maps the
+        kernel programs over its own lanes (BFVContext._lane_map), so
+        they are replicated over the mesh; otherwise they are uncommitted
+        one-device arrays.  Keys move one at a time, so no device ever
+        holds two copies of the whole set."""
+        ctx = self.shard_ctx
+        mesh = None
+        if ctx is not None and ctx.mesh is not None and ctx.limb_mesh is None:
+            mesh = ctx.mesh
+        if mesh is self._home_mesh:
+            return
+        if mesh is None:
+            move = lambda x: jax.numpy.asarray(np.asarray(x))
+        else:
+            rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+            move = lambda x: jax.device_put(x, rep)
+        self.ctx.place_tables(mesh)
+        for key in [self.keys.rlk, *self.keys.gks.values()]:
+            key.b = move(key.b)
+            key.a = move(key.a)
+        self._home_mesh = mesh
+
+    def circuit_lanes(self, kind: str) -> int | None:
+        """Lanes per stacked comparison circuit that fit the device's free
+        memory, or None where the device reports no memory (CPU).
+
+        A stacked circuit keeps its intermediates alive for every lane at
+        once (compare.live_ciphertexts).  At the paper's size one LT lane
+        peaks near 6 GB, so fusing a query's range predicates into one
+        launch would outgrow a 16 GB chip that also holds 7.5 GB of keys.
+        Under a data mesh each device holds its own lanes, so the count
+        scales with the mesh."""
+        stats = jax.devices()[0].memory_stats()
+        if not stats:
+            return None
+        # no reported limit: assume nothing is free, i.e. one lane each
+        free = stats.get("bytes_limit", 0) - stats.get("bytes_in_use", 0)
+        ctx = self.shard_ctx
+        devices = ctx.shards if ctx is not None and ctx.mesh is not None else 1
+        return lanes_within(free, kind, self.t, self.params) * devices
 
     def _nblocks(self, ct) -> int:
         return ct.nblocks if isinstance(ct, CiphertextBatch) else 1
@@ -363,10 +423,12 @@ class BFVBackend(_BackendBase):
         v = np.zeros(self.slots, dtype=np.int64)
         arr = np.asarray(vec, dtype=np.int64) % self.t
         v[: len(arr)] = arr
+        self._home()
         return self._set_d(self.ctx.encrypt(self.enc.encode(v), self.keys.pk), 0)
 
     def decrypt(self, ct) -> np.ndarray:
         self.stats.decrypt += self._nblocks(ct)
+        self._home()
         polys = self.ctx.decrypt(ct, self.keys.sk)
         if isinstance(ct, CiphertextBatch):
             # live lanes only: shard padding never reaches the client
@@ -436,6 +498,7 @@ class BFVBackend(_BackendBase):
                 self.model.mul(a.noise, b.noise)), "mul")
         self._charge("mul", a, b)
         self._charge_gather(a, b)
+        self._home()
         out = self.ctx.mul(a, b, self.keys.rlk, mesh=self._limb_mesh())
         return self._set_d(out, max(self._d(a), self._d(b)) + 1)
 
@@ -453,6 +516,7 @@ class BFVBackend(_BackendBase):
             poly = np.stack([np.asarray(self.enc.encode(r)) for r in rows])
         else:
             poly = self.enc.encode(arr)
+        self._home()
         return self._set_d(self.ctx.mul_plain(a, poly), self._d(a) + 1)
 
     def add_plain(self, a, vec):
@@ -473,17 +537,25 @@ class BFVBackend(_BackendBase):
         return self._set_d(self.ctx.sub_from_scalar(c, a), self._d(a))
 
     def dot_plain(self, cts: list, coeffs) -> Ciphertext:
-        """sum_i coeffs[i] * cts[i] — the BSGS baby-step inner product.
-        Same accounting as len(cts) mul_scalar + adds."""
-        acc = None
-        for ct, c in zip(cts, coeffs):
-            c = int(c) % self.t
-            if c == 0:
-                continue
-            term = self.mul_scalar(ct, c)
-            acc = term if acc is None else self.add(acc, term)
-        assert acc is not None
-        return acc
+        """sum_i coeffs[i] * cts[i] — the BSGS baby-step inner product —
+        as one device program (an LT runs ~150k such terms: one launch
+        each would be host-dispatch bound).  Charged, noise- and
+        depth-tracked exactly as the mul_scalar/add sequence."""
+        cs = [int(c) % self.t for c in coeffs]
+        terms = [(ct, c) for ct, c in zip(cts, cs) if c]
+        assert terms, "all-zero dot"
+        noise = None
+        for ct, c in terms:
+            self._charge("mul_scalar", ct)
+            term = self.model.mul_scalar(ct.noise, c)
+            if noise is None:
+                noise = term
+            else:
+                self._charge("add", ct)
+                noise = self.model.add(noise, term)
+        out = self.ctx._like(terms[0][0], self.ctx.dot_scalars(
+            [ct.data for ct in cts], cs), noise)
+        return self._set_d(out, max(self._d(ct) for ct, _ in terms))
 
     # -- data movement ---------------------------------------------------
     def rotate(self, a, step: int):
@@ -491,6 +563,7 @@ class BFVBackend(_BackendBase):
         hops = bin(step % (self.slots // 2)).count("1")
         self._charge("rotate", a, mult=hops)
         self._charge_gather(a, mult=hops)      # one kswitch per pow-2 hop
+        self._home()
         return self._set_d(
             self.ctx.rotate_rows(a, step, self.keys.gks,
                                  mesh=self._limb_mesh()), self._d(a))
@@ -498,6 +571,7 @@ class BFVBackend(_BackendBase):
     def swap_rows(self, a):
         self._charge("rotate", a)
         self._charge_gather(a)
+        self._home()
         return self._set_d(
             self.ctx.swap_rows(a, self.keys.gks, mesh=self._limb_mesh()),
             self._d(a))

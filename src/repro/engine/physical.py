@@ -281,6 +281,21 @@ class AtomEvaluator:
     def _circuit(self, kind: str, x):
         return cmp.eq_zero(self.bk, x) if kind == "eq" else cmp.lt_zero(self.bk, x)
 
+    def _run_circuit(self, kind: str, blocks: list) -> list:
+        """The circuit over `blocks` as stacked launches of at most
+        `bk.circuit_lanes(kind)` lanes (one launch when unbounded): a
+        stacked circuit keeps its intermediates alive for every lane."""
+        bk = self.bk
+        step = bk.circuit_lanes(kind) or len(blocks)
+        out = []
+        for i in range(0, len(blocks), step):
+            chunk = blocks[i:i + step]
+            if len(chunk) == 1:
+                out.append(self._circuit(kind, chunk[0]))
+            else:
+                out += bk.unstack_blocks(self._circuit(kind, bk.stack_blocks(chunk)))
+        return out
+
     def flush(self) -> None:
         """Run every pending circuit.  With fusion, all atoms of a kind
         share ONE stacked launch; op_log still charges one logical eq/cmp
@@ -300,20 +315,13 @@ class AtomEvaluator:
                 continue
             if not self.fuse or len(atoms) == 1:
                 for atom in atoms:
-                    zs = self._z_blocks(atom)
-                    x = bk.stack_blocks(zs) if len(zs) > 1 else zs[0]
-                    out = self._circuit(kind, x)
-                    outs = bk.unstack_blocks(out) if len(zs) > 1 else [out]
-                    self.cache.insert(bk, atom, outs)
+                    self.cache.insert(bk, atom,
+                                      self._run_circuit(kind, self._z_blocks(atom)))
                 self._pending[kind] = []
                 continue
             per_atom = [(atom, self._z_blocks(atom)) for atom in atoms]
-            all_blocks = [b for _, zs in per_atom for b in zs]
-            if len(all_blocks) == 1:
-                out_blocks = [self._circuit(kind, all_blocks[0])]
-            else:
-                out = self._circuit(kind, bk.stack_blocks(all_blocks))
-                out_blocks = bk.unstack_blocks(out)
+            out_blocks = self._run_circuit(
+                kind, [b for _, zs in per_atom for b in zs])
             if hasattr(bk, "op_log"):     # one logical circuit per atom
                 bk.op_log["eq" if kind == "eq" else "cmp"] += len(atoms) - 1
             i = 0

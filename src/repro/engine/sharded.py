@@ -51,8 +51,17 @@ This module owns the runtime plumbing:
   combines partial sums with `jax.lax.psum` over "data" (limb slices
   stay put — the fold is limb-local).  Pad lanes are excluded with a
   0/1 lane-weight vector so the whole thing stays a single launch.
-  The shard_map body runs under `limbops.force_ref()` because Pallas
-  interpret mode cannot trace inside a shard_map region.
+
+* Lane-split kernels: on a 1-D data mesh, BFVBackend._home replicates
+  the switching keys and both bases' tables over the mesh once
+  (BFVContext.place_tables).  The kernel-bearing BFV programs
+  (multiply, plaintext multiply, rotation, encryption, decryption) then
+  run under shard_map (core/bfv.py `_lane_map`): a batch split by lane
+  over "data" stays split, each device maps the one-ciphertext program
+  over its own lanes with `lax.map`, and a lone ciphertext runs
+  replicated.  The bodies call whatever `LimbOps` resolved to: compiled
+  kernels on a TPU, interpret-mode kernels or the reference on CPU.
+  The programs hold no collective; the fold's psum is the only one.
 
 Parity contract: padding lanes (block or limb) are exact additive
 identities, `_count`/`_nblocks` keep returning *live* lane counts, and
@@ -69,9 +78,7 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 
-from ..core import limbops
 from ..launch.mesh import make_query_mesh, make_scan_mesh
 from ..runtime.elastic import elastic_limb_plan, elastic_scan_plan
 
@@ -262,8 +269,10 @@ def make_shard_context(shards: int, mesh="auto", limb_shards: int = 1,
                        ring_n: int = 0) -> ShardContext:
     """Build a context; 'auto' attaches a real mesh when the host has
     enough devices (e.g. under XLA_FLAGS=--xla_force_host_platform_
-    device_count=8), else runs logical-only (padding + ledger, single
-    device) so shard plans stay testable on one chip.
+    device_count=8).  Off-TPU it otherwise runs logical-only (padding +
+    ledger, single device) so shard plans stay testable on one CPU; on a
+    TPU a data axis (shards > 1) that finds too few devices raises
+    instead of quietly running on one chip.
 
     The model axis gets real device placement only when the limb count
     divides evenly (k % M == 0) — otherwise limb sharding stays a
@@ -278,6 +287,9 @@ def make_shard_context(shards: int, mesh="auto", limb_shards: int = 1,
             mesh = make_query_mesh(shards, limb_shards)
         elif 1 < shards <= ndev:
             mesh = make_scan_mesh(shards)
+        elif shards > 1 and jax.default_backend() == "tpu":
+            raise ValueError(f"shards={shards} needs a {shards}-device data "
+                             f"mesh but only {ndev} TPU devices are visible")
         else:
             mesh = None
     return ShardContext(shards, mesh, limb_shards=limb_shards,
@@ -363,9 +375,9 @@ def _fold_psum(data, weights, *, mesh):
         local = jnp.sum(d * w[:, None, None, None], axis=0)
         return jax.lax.psum(local, "data")
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P("data", None, limb, None), P("data")),
-                     out_specs=P(None, limb, None))(data, weights)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P("data", None, limb, None), P("data")),
+                         out_specs=P(None, limb, None))(data, weights)
 
 
 def sharded_fold(data, live: int, mesh):
@@ -377,5 +389,4 @@ def sharded_fold(data, live: int, mesh):
     the reduction)."""
     nphys = data.shape[0]
     weights = (jnp.arange(nphys) < live).astype(data.dtype)
-    with limbops.force_ref():
-        return _fold_psum(data, weights, mesh=mesh)
+    return _fold_psum(data, weights, mesh=mesh)

@@ -889,4 +889,6 @@ def _main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from ..runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(_main())

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -73,7 +75,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                     softcap: float | None = None, sm_scale: float | None = None,
-                    blk_q: int = 128, blk_k: int = 128, interpret: bool = True):
+                    blk_q: int = 128, blk_k: int = 128, interpret: bool | None = None):
     """q: (bh, sq, d); k, v: (bh, sk, d) — heads pre-flattened into batch.
 
     GQA is handled by the caller repeating KV heads (or flattening the
@@ -105,7 +107,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
             pltpu_scratch((blk_q, 1)),
             pltpu_scratch((blk_q, d)),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
 
 

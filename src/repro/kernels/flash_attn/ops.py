@@ -1,6 +1,6 @@
 """Public wrapper: multi-head attention with GQA handling.
 
-On TPU (interpret=False) this is the production attention for train /
+On TPU (compiled, the platform default) this is the production attention for train /
 prefill.  The CPU dry-run and the models' default path use ref.py's dense
 attention; smoke tests run this wrapper in interpret mode to prove the
 kernel integrates.
@@ -13,7 +13,7 @@ from .flash_attn import flash_attention
 
 
 def mha(q, k, v, *, causal: bool = True, window: int | None = None,
-        softcap: float | None = None, interpret: bool = True):
+        softcap: float | None = None, interpret: bool | None = None):
     """q: (B, H, Sq, D); k, v: (B, Hkv, Sk, D) with H % Hkv == 0."""
     B, H, Sq, D = q.shape
     Hkv = k.shape[1]

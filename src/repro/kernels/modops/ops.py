@@ -17,20 +17,20 @@ def _mu_for(primes: tuple[int, ...]) -> jnp.ndarray:
     return _MU[primes]
 
 
-def mul_mod(a_i64, b_i64, primes: tuple[int, ...], *, interpret: bool = True):
+def mul_mod(a_i64, b_i64, primes: tuple[int, ...], *, interpret: bool | None = None):
     q = jnp.asarray(np.array(primes, dtype=np.uint32))[:, None]
     out = mul_mod_pallas(a_i64.astype(jnp.uint32), b_i64.astype(jnp.uint32),
                          q, _mu_for(tuple(primes)), interpret=interpret)
     return out.astype(jnp.int64)
 
 
-def add_mod(a_i64, b_i64, primes: tuple[int, ...], *, interpret: bool = True):
+def add_mod(a_i64, b_i64, primes: tuple[int, ...], *, interpret: bool | None = None):
     q = jnp.asarray(np.array(primes, dtype=np.uint32))[:, None]
     return add_mod_pallas(a_i64.astype(jnp.uint32), b_i64.astype(jnp.uint32),
                           q, interpret=interpret).astype(jnp.int64)
 
 
-def sub_mod(a_i64, b_i64, primes: tuple[int, ...], *, interpret: bool = True):
+def sub_mod(a_i64, b_i64, primes: tuple[int, ...], *, interpret: bool | None = None):
     q = jnp.asarray(np.array(primes, dtype=np.uint32))[:, None]
     return sub_mod_pallas(a_i64.astype(jnp.uint32), b_i64.astype(jnp.uint32),
                           q, interpret=interpret).astype(jnp.int64)

@@ -8,6 +8,7 @@ from 16-bit limb splitting on uint32 vectors:
                  one mulhi + one wrapping mul-sub (twiddles, plaintexts)
   barrett_mulmod general a * b mod q for q in (2^28, 2^30): full 60-bit
                  product in (hi, lo) halves, quotient via mu = 2^60 / q
+  barrett_reduce the same reduction of a 60-bit int64 value
 
 All functions are shape-polymorphic jnp code: they run identically inside
 Pallas kernel bodies and in host-side tests.
@@ -59,18 +60,37 @@ def barrett_mulmod(a, b, q, mu):
     """General a*b mod q (a, b < q < 2^30) on uint32 lanes.
 
     P = a*b < 2^60 held as (hi, lo); x1 = floor(P / 2^29) < 2^31;
-    qhat = floor(x1 * mu / 2^31); r = P - qhat*q in [0, 3q) -> 2 csubs.
+    qhat = floor(x1 * mu / 2^31) in [P/q - 3, P/q]; r = P - qhat*q in
+    [0, 4q) -> 3 csubs (the third is needed only for q <= 2^29).
     """
     a = a.astype(jnp.uint32)
     b = b.astype(jnp.uint32)
     lo = mullo_u32(a, b)
     hi = mulhi_u32(a, b)                              # < 2^28
     x1 = (hi << 3) | (lo >> 29)                       # floor(P / 2^29)
+    return _barrett_tail(lo, x1, q, mu)
+
+
+def _barrett_tail(lo, x1, q, mu):
+    """x mod q from x's low word and x1 = floor(x / 2^29)."""
     qhat = (mulhi_u32(x1, mu) << 1) | (mullo_u32(x1, mu) >> 31)
     r = lo - mullo_u32(qhat, q)                       # exact in low 32 bits
-    r = jnp.where(r >= q, r - q, r)
-    r = jnp.where(r >= q, r - q, r)
+    for _ in range(3):
+        r = jnp.where(r >= q, r - q, r)
     return r
+
+
+def barrett_reduce(x, q, mu):
+    """x mod q for int64 x in [0, 2^60), q in (2^28, 2^30), mu as above.
+
+    The reduction half of `barrett_mulmod` with the 60-bit value given
+    directly: uint32 lane arithmetic only, so it also replaces a 64-bit
+    remainder (a long software division on chips without a 64-bit ALU)
+    outside the kernels.  Returns uint32.
+    """
+    lo = (x & 0xFFFFFFFF).astype(jnp.uint32)
+    x1 = (x >> 29).astype(jnp.uint32)                  # floor(x / 2^29) < 2^31
+    return _barrett_tail(lo, x1, q, mu)
 
 
 def add_mod(a, b, q):

@@ -271,4 +271,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from ..runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

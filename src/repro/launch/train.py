@@ -93,4 +93,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from ..runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

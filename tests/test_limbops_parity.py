@@ -1,7 +1,7 @@
 """Kernel <-> reference parity for the batched limb-op dispatch layer.
 
 Exercises the Pallas `mul_mod/add_mod/sub_mod` and forward/inverse NTT
-kernels (interpret mode on CPU) against the pure-jnp `*_ref` oracles
+kernels (interpret mode off-TPU) against the pure-jnp `*_ref` oracles
 through `core/limbops.LimbOps`, across several limb counts, batch
 shapes, non-tile-aligned lengths, and edge values (0, q-1).
 """
@@ -137,12 +137,19 @@ def test_ntt_edge_values(param_grid):
             assert np.array_equal(np.asarray(pal.intt(a)), np.asarray(ref.intt(a)))
 
 
-def test_aux_base_falls_back_to_ref():
-    """31-bit HPS auxiliary primes sit outside the Barrett window."""
+def test_both_bases_in_window_and_outside_raises():
+    """Q and the HPS auxiliary base P both sit in the Barrett window, so
+    both run the kernels; asking the kernels for a base outside the
+    window raises instead of quietly running the reference."""
     p = make_params(n=64, t=257, k=1)
-    assert not pallas_supported(p.P.primes)
-    assert LimbOps(p.P, backend="pallas").backend == "ref"
+    assert pallas_supported(p.Q.primes) and pallas_supported(p.P.primes)
+    assert LimbOps(p.P, backend="pallas").backend == "pallas"
     assert LimbOps(p.Q, backend="pallas").backend == "pallas"
+    wide = find_ntt_primes(64, 31, 2)
+    assert not pallas_supported(wide)
+    with pytest.raises(ValueError, match="Barrett|2\\^28"):
+        resolve_backend("pallas", wide)
+    assert resolve_backend("ref", wide) == "ref"
 
 
 def test_resolve_backend_flags():

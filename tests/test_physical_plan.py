@@ -261,3 +261,42 @@ def test_via_plan_translated_join_on_real_he(bfv_db, bfv_micro):
     dim, fact = bfv_db.plain["dim"], bfv_db.plain["fact"]
     m = (dim["flag"][fact["fk"] - 1] == 1) & (fact["v"] < 15)
     assert got == {"vol": int(fact["v"][m].sum()) % t, "n": int(m.sum()) % t}
+
+
+def test_circuit_lane_cap_keeps_masks_and_counts(bfv_db, bfv_micro, monkeypatch):
+    """A backend that bounds the lanes of a stacked circuit (device
+    memory, BFVBackend.circuit_lanes) gets the same masks and op counts
+    from more, smaller launches."""
+    bk = bfv_micro
+    table = bfv_db.tables["sales"]
+    expr = And((Pred("region", "=", "N"), Pred("qty", "=", 3)))
+
+    def run():
+        bk.stats.reset()
+        mask = Planner(bfv_db, optimized=True).where_mask(table, expr)
+        return [bk.decrypt(b) for b in mask], bk.stats.clone()
+
+    masks, stats = run()
+    monkeypatch.setattr(bk, "circuit_lanes", lambda kind: 1)
+    capped, capped_stats = run()
+    for a, b in zip(masks, capped):
+        np.testing.assert_array_equal(a, b)
+    assert (capped_stats.mul, capped_stats.add) == (stats.mul, stats.add)
+    assert capped_stats.launches > stats.launches
+    plain = bfv_db.plain["sales"]
+    rid = table.schema.col("region").dictionary["N"]
+    want = ((plain["region"] == rid) & (plain["qty"] == 3)).astype(np.int64)
+    np.testing.assert_array_equal(masks[0][:len(want)], want)
+
+
+def test_lanes_within_device_memory():
+    """At the paper's size one LT lane outgrows what a 16 GB chip has
+    free beside its keys; EQ lanes are small.  Kinds are the atoms'
+    circuit names."""
+    from repro.core.noise import paper_profile
+    from repro.engine.backend import lanes_within
+    p = paper_profile()
+    free = 16_909_336_064 - 9_102_939_136      # a v5e after keygen
+    assert lanes_within(free, "lt", p.t, p) == 1
+    assert lanes_within(free, "eq", p.t, p) == 15
+    assert lanes_within(0, "lt", p.t, p) == 1

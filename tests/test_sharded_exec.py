@@ -464,23 +464,54 @@ def test_mock_query_with_real_mesh(db_mb):
 
 
 # ---------------------------------------------------------------------------
-# 10. limbops.force_ref: kernel dispatch pinned to ref inside shard_map.
+# 10. Kernel-bearing programs run once per lane (core/bfv.py _lane_map):
+#     batch results equal the one-ciphertext program applied lane by lane.
 # ---------------------------------------------------------------------------
 
-def test_force_ref_overrides_kernel_dispatch(micro_params):
-    from repro.core import limbops
-    lo = limbops.LimbOps(micro_params.Q)
-    ref = limbops.LimbOps(micro_params.Q, backend="ref")
-    rng = np.random.default_rng(1)
-    x = rng.integers(0, np.asarray(micro_params.Q.q).min(),
-                     (lo.k, lo.n), dtype=np.int64)
-    outside = lo._use_ref()
-    with limbops.force_ref():
-        assert lo._use_ref()
-        with limbops.force_ref():              # reentrant
-            assert lo._use_ref()
-            np.testing.assert_array_equal(
-                np.asarray(lo.ntt(x)), np.asarray(ref.ntt(x)))
-        assert lo._use_ref()
-    assert lo._use_ref() == outside            # counter fully unwinds
-    assert lo.backend in ("ref", "pallas")     # attr itself untouched
+def test_lane_map_equals_per_block_programs(bfv_micro):
+    import jax
+    bk = bfv_micro
+    ctx = bk.ctx
+    blocks = [bk.encrypt(np.arange(bk.slots) % 11 + i) for i in range(3)]
+    batch = ctx.stack_cts(blocks)
+    got = ctx.mul(batch, batch, bk.keys.rlk).data
+    for i, b in enumerate(blocks):
+        np.testing.assert_array_equal(
+            np.asarray(got[i]), np.asarray(ctx.mul(b, b, bk.keys.rlk).data))
+    rot = ctx.rotate_rows(batch, 1, bk.keys.gks).data
+    np.testing.assert_array_equal(
+        np.asarray(rot[2]), np.asarray(ctx.rotate_rows(blocks[2], 1, bk.keys.gks).data))
+    leaves, tree = jax.tree.flatten(ctx.limb_q)            # tables are leaves
+    back = jax.tree.unflatten(tree, leaves)
+    assert (back.backend, back.interpret, back.k) == (
+        ctx.limb_q.backend, ctx.limb_q.interpret, ctx.limb_q.k)
+
+
+@multidevice
+def test_lane_map_on_data_mesh_equals_one_device(bfv_micro):
+    """On a data mesh the kernel programs run under shard_map, each
+    device mapping the one-ciphertext program over its own lanes: same
+    residues as the one-device lane loop, results still split by lane,
+    and a lone ciphertext runs replicated."""
+    bk = bfv_micro
+    blocks = [bk.encrypt(np.arange(bk.slots) % 11 + i) for i in range(4)]
+    one = bk.ctx.stack_cts(blocks)
+    want_mul = np.asarray(bk.ctx.mul(one, blocks[1], bk.keys.rlk).data)
+    want_rot = np.asarray(bk.ctx.rotate_rows(one, 3, bk.keys.gks).data)
+    want_dec = bk.decrypt(blocks[2])
+    want_pair = np.asarray(bk.ctx.mul_plain(bk.ctx.stack_cts(blocks[:2]),
+                                            np.arange(bk.slots) % 5).data)
+    with activate(bk, make_shard_context(2)):
+        batch = bk.stack_blocks(blocks)                 # two lanes per device
+        got_mul = bk.mul(batch, blocks[1]).data
+        got_rot = bk.rotate(batch, 3).data
+        got_dec = bk.decrypt(blocks[2])
+        pair = bk.stack_blocks(blocks[:2])              # one lane per device
+        got_pair = bk.ctx.mul_plain(pair, np.arange(bk.slots) % 5).data
+        assert bk.ctx.mesh is not None
+    assert len(got_mul.sharding.device_set) == 2
+    assert not got_mul.sharding.is_fully_replicated
+    np.testing.assert_array_equal(np.asarray(got_mul), want_mul)
+    np.testing.assert_array_equal(np.asarray(got_rot), want_rot)
+    np.testing.assert_array_equal(got_dec, want_dec)
+    np.testing.assert_array_equal(np.asarray(got_pair), want_pair)
