@@ -43,6 +43,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+import types
 
 import jax
 import jax.numpy as jnp
@@ -153,10 +154,11 @@ def _ksw_gathered(poly, kb, ka, q, psi, ipsi, ninv, *, mesh, data_sharded):
     specs = (P(dspec, "model", None), P(None, "model", None),
              P(None, "model", None), P("model"), P("model", None),
              P("model", None), P("model"))
-    return jax.shard_map(body, mesh=mesh, in_specs=specs,
-                         out_specs=(P(dspec, "model", None),
-                                    P(dspec, "model", None)))(
-        poly, kb, ka, q, psi, ipsi, ninv)
+    with jax.named_scope("he.keyswitch"):
+        return jax.shard_map(body, mesh=mesh, in_specs=specs,
+                             out_specs=(P(dspec, "model", None),
+                                        P(dspec, "model", None)))(
+            poly, kb, ka, q, psi, ipsi, ninv)
 
 
 class BFVContext:
@@ -165,11 +167,20 @@ class BFVContext:
     `backend` / `interpret` select the limb-level execution path (see
     module docstring); all ciphertext ops accept `Ciphertext` and
     `CiphertextBatch` interchangeably and preserve the input type.
+    `stats` is the object whose `dispatches` field counts the jitted
+    programs the host calls (the engine passes its OpStats).
+
+    Device programs carry `jax.named_scope` names, which reach each HLO
+    instruction's `op_name`: he.keyswitch, he.tensor, he.hps, he.galois,
+    he.dot, he.encrypt, he.decrypt, and he.ntt / he.intt (LimbOps).
     """
 
     def __init__(self, params: HEParams, seed: int = 0,
-                 backend: str | None = None, interpret: bool | None = None):
+                 backend: str | None = None, interpret: bool | None = None,
+                 stats=None):
         self.params = params
+        self.stats = stats if stats is not None else types.SimpleNamespace(
+            dispatches=0)
         self.noise_model = NoiseModel(params)
         self.rng = np.random.default_rng(seed)
         p = params
@@ -222,6 +233,11 @@ class BFVContext:
         self._galois_tabs = jax.tree.map(move, self._galois_tabs)
         self.mesh = mesh
 
+    def _dispatch(self, prog, *args, **kwargs):
+        """Call one jitted program: one host dispatch."""
+        self.stats.dispatches += 1
+        return prog(*args, **kwargs)
+
     def _lane_map(self, prog, shared, operands, batched):
         """prog(*shared, *operands) with a one-ciphertext program:
         operands[i] is a (B, ...) batch where batched[i], else one value
@@ -229,10 +245,11 @@ class BFVContext:
         if self.mesh is not None:
             return self._lane_map_mesh(prog, shared, operands, batched)
         if not any(batched):
-            return prog(*shared, *operands)
+            return self._dispatch(prog, *shared, *operands)
         B = next(x for x, b in zip(operands, batched) if b).shape[0]
         return jnp.stack([
-            prog(*shared, *(x[i] if b else x for x, b in zip(operands, batched)))
+            self._dispatch(prog, *shared, *(x[i] if b else x
+                                            for x, b in zip(operands, batched)))
             for i in range(B)])
 
     def _lane_map_mesh(self, prog, shared, operands, batched):
@@ -246,8 +263,8 @@ class BFVContext:
         lane = P("data") if split else P()
         operands = [jax.device_put(x, jax.sharding.NamedSharding(
             mesh, lane if b else P())) for x, b in zip(operands, batched)]
-        return self._lane_program(prog, len(shared), batched, split)(
-            *shared, *operands)
+        return self._dispatch(self._lane_program(prog, len(shared), batched, split),
+                              *shared, *operands)
 
     def _lane_program(self, prog, nshared: int, batched: tuple, split: bool):
         """The jitted shard_map program `_lane_map_mesh` runs (cached)."""
@@ -377,6 +394,7 @@ class BFVContext:
             (jnp.asarray(m_poly), u, e0, e1, pk.b_ntt, pk.a_ntt), (False,) * 6)
         return Ciphertext(data=data, noise=self.noise_model.fresh(), params=self.params)
 
+    @jax.named_scope("he.encrypt")
     def _encrypt_impl(self, lq, m, u, e0, e1, pkb, pka):
         q = self.qQ[:, None]
         u_ntt = lq.ntt(u)
@@ -393,6 +411,7 @@ class BFVContext:
         return self._lane_map(self._decrypt_j, (self.limb_q,),
                               (ct.data, sk.s_ntt), (ct.data.ndim == 4, False))
 
+    @jax.named_scope("he.decrypt")
     def _decrypt_impl(self, lq, data, s_ntt):
         p = self.params
         q = self.qQ[:, None]
@@ -427,10 +446,11 @@ class BFVContext:
         raise ValueError(op)
 
     def _elementwise(self, op: str, x, y):
-        return self._elementwise_j(self.limb_q, x, y, op=op)
+        return self._dispatch(self._elementwise_j, self.limb_q, x, y, op=op)
 
     DOT_TERMS = 32      # terms per inner-product program (one compile)
 
+    @jax.named_scope("he.dot")
     def _dot_impl(self, lq, acc, datas, cs):
         # acc < 2^30 plus DOT_TERMS terms < 2^30 * 2^17: below 2^60
         return lq.reduce(acc + sum(d * cs[i] for i, d in enumerate(datas)))
@@ -447,7 +467,8 @@ class BFVContext:
             part = datas[i:i + g]
             pad = g - len(part)
             cs = jnp.asarray(coeffs[i:i + g] + [0] * pad, dtype=jnp.int64)
-            acc = self._dot_j(self.limb_q, acc, tuple(part + [part[0]] * pad), cs)
+            acc = self._dispatch(self._dot_j, self.limb_q, acc,
+                                 tuple(part + [part[0]] * pad), cs)
         return acc
 
     def add(self, a, b):
@@ -509,6 +530,7 @@ class BFVContext:
 
     # ------------------------------------------------- HPS base conversion
     @staticmethod
+    @jax.named_scope("he.hps")
     def _fbc(x, conv, lin: LimbOps, lout: LimbOps):
         """Exact fast base conversion of the centered value of x.
 
@@ -536,8 +558,8 @@ class BFVContext:
                 self._mul_j, (self.limb_q, self.limb_p, rlk.b, rlk.a),
                 (a.data, b.data), (a.data.ndim == 4, b.data.ndim == 4))
         else:
-            r0, r1, r2 = self._mul_tensor_j(self.limb_q, self.limb_p,
-                                            a.data, b.data)
+            r0, r1, r2 = self._dispatch(self._mul_tensor_j, self.limb_q,
+                                        self.limb_p, a.data, b.data)
             ks0, ks1 = self.kswitch_gathered(r2, rlk, mesh)
             q = self.qQ[:, None]
             data = jnp.stack([(r0 + ks0) % q, (r1 + ks1) % q], axis=-3)
@@ -545,6 +567,7 @@ class BFVContext:
         return self._like(self._pick(a, b), data,
                           nz.keyswitch(nz.mul(a.noise, b.noise)))
 
+    @jax.named_scope("he.tensor")
     def _mul_tensor_impl(self, lq, lp, da, db):
         """Steps 1-4 of the HPS multiply: the degree-2 tensor scaled back
         to base Q, before relinearization."""
@@ -587,6 +610,7 @@ class BFVContext:
         return jnp.stack([lq.reduce(r0 + ks0), lq.reduce(r1 + ks1)], axis=-3)
 
     # --------------------------------------------------------- key switch
+    @jax.named_scope("he.keyswitch")
     def _kswitch_inner(self, lq, poly, ksk_b, ksk_a):
         """Key-switch `poly` (coeff domain, (..., k, n)): coeff-domain pair."""
         q = self.qQ[:, None]
@@ -619,12 +643,13 @@ class BFVContext:
         data_ax = mesh.shape.get("data", 1)
         data_sharded = B > 1 and B % data_ax == 0
         tabs = self.limb_q.arrays
-        b, a = _ksw_gathered(p3, ksk.b, ksk.a, self.qQ, tabs["psi"],
-                             tabs["ipsi"], tabs["ninv"], mesh=mesh,
-                             data_sharded=data_sharded)
+        b, a = self._dispatch(_ksw_gathered, p3, ksk.b, ksk.a, self.qQ,
+                              tabs["psi"], tabs["ipsi"], tabs["ninv"],
+                              mesh=mesh, data_sharded=data_sharded)
         return b.reshape(poly.shape), a.reshape(poly.shape)
 
     # ------------------------------------------------------------ rotation
+    @jax.named_scope("he.galois")
     def _apply_galois_impl(self, data, src, sign):
         return (sign * data[..., src]) % self.qQ[:, None]
 
@@ -632,7 +657,8 @@ class BFVContext:
         """sigma_g (as its gather table) then key-switch back to s: the
         whole rotation, one program for every Galois element."""
         q = self.qQ[:, None]
-        rot = lq.reduce(sign * data[..., src] + q)
+        with jax.named_scope("he.galois"):
+            rot = lq.reduce(sign * data[..., src] + q)
         ks0, ks1 = self._kswitch_inner(lq, rot[..., 1, :, :], ksk_b, ksk_a)
         return jnp.stack([lq.reduce(rot[..., 0, :, :] + ks0), ks1], axis=-3)
 
@@ -643,7 +669,8 @@ class BFVContext:
                                   (self.limb_q, gk.b, gk.a, src, sign),
                                   (ct.data,), (ct.data.ndim == 4,))
         else:
-            rot = self._apply_galois_j(ct.data, *self._galois_tabs[g])
+            rot = self._dispatch(self._apply_galois_j, ct.data,
+                                 *self._galois_tabs[g])
             ks0, ks1 = self.kswitch_gathered(rot[..., 1, :, :], gk, mesh)
             c0 = (rot[..., 0, :, :] + ks0) % self.qQ[:, None]
             data = jnp.stack([c0, ks1], axis=-3)

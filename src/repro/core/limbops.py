@@ -220,6 +220,7 @@ class LimbOps:
         return self._tile(self.q, B)[:, None]
 
     # -------------------------------------------------------------- NTT
+    @jax.named_scope("he.ntt")
     def ntt(self, a):
         """Forward negacyclic NTT over (..., k, n)."""
         shape = a.shape
@@ -232,6 +233,7 @@ class LimbOps:
                                  interpret=self.interpret).astype(jnp.int64)
         return out.reshape(shape)
 
+    @jax.named_scope("he.intt")
     def intt(self, a):
         """Inverse negacyclic NTT over (..., k, n)."""
         shape = a.shape

@@ -55,6 +55,7 @@ import numpy as np
 from ..core import compare
 from ..core.bfv import BFVContext, Ciphertext, CiphertextBatch, Keys
 from ..runtime import faults
+from ..runtime.spans import HE, span
 from ..core.encoder import BatchEncoder
 from ..core.noise import NoiseModel, NoiseProfile, paper_profile
 from ..core.params import HEParams
@@ -75,6 +76,8 @@ class OpStats:
     max_depth: int = 0      # deepest multiplicative chain observed
     launches: int = 0       # primitive *calls* (a batched op over N blocks
                             # is 1 launch but charges N to the op counters)
+    dispatches: int = 0     # jitted device programs the host called
+                            # (BFVContext; the mock runs none)
 
     def clone(self) -> "OpStats":
         return dataclasses.replace(self)
@@ -284,8 +287,8 @@ class BFVBackend(_BackendBase):
         self.params = params
         self.t = params.t
         self.slots = params.n
-        self.ctx = BFVContext(params, seed=seed,
-                              backend=kernel_backend, interpret=interpret)
+        self.ctx = BFVContext(params, seed=seed, backend=kernel_backend,
+                              interpret=interpret, stats=self.stats)
         self.keys: Keys = self.ctx.keygen()
         self.enc = BatchEncoder(params)
         self.model = self.ctx.noise_model
@@ -423,18 +426,21 @@ class BFVBackend(_BackendBase):
         v = np.zeros(self.slots, dtype=np.int64)
         arr = np.asarray(vec, dtype=np.int64) % self.t
         v[: len(arr)] = arr
-        self._home()
-        return self._set_d(self.ctx.encrypt(self.enc.encode(v), self.keys.pk), 0)
+        with span(HE + "encrypt", blocks=1):
+            self._home()
+            out = self.ctx.encrypt(self.enc.encode(v), self.keys.pk)
+        return self._set_d(out, 0)
 
     def decrypt(self, ct) -> np.ndarray:
         self.stats.decrypt += self._nblocks(ct)
-        self._home()
-        polys = self.ctx.decrypt(ct, self.keys.sk)
-        if isinstance(ct, CiphertextBatch):
-            # live lanes only: shard padding never reaches the client
-            return np.stack([np.asarray(self.enc.decode(polys[i]))
-                             for i in range(ct.nblocks)])
-        return np.asarray(self.enc.decode(polys))
+        with span(HE + "decrypt", blocks=self._nblocks(ct)):
+            self._home()
+            polys = self.ctx.decrypt(ct, self.keys.sk)
+            if isinstance(ct, CiphertextBatch):
+                # live lanes only: shard padding never reaches the client
+                return np.stack([np.asarray(self.enc.decode(polys[i]))
+                                 for i in range(ct.nblocks)])
+            return np.asarray(self.enc.decode(polys))
 
     def refresh(self, ct: Ciphertext) -> Ciphertext:
         """Client-side re-encryption (NSHEDB's trust model allows it; the
@@ -498,8 +504,9 @@ class BFVBackend(_BackendBase):
                 self.model.mul(a.noise, b.noise)), "mul")
         self._charge("mul", a, b)
         self._charge_gather(a, b)
-        self._home()
-        out = self.ctx.mul(a, b, self.keys.rlk, mesh=self._limb_mesh())
+        with span(HE + "mul", blocks=max(self._nblocks(a), self._nblocks(b))):
+            self._home()
+            out = self.ctx.mul(a, b, self.keys.rlk, mesh=self._limb_mesh())
         return self._set_d(out, max(self._d(a), self._d(b)) + 1)
 
     def mul_plain(self, a, vec):
@@ -516,8 +523,10 @@ class BFVBackend(_BackendBase):
             poly = np.stack([np.asarray(self.enc.encode(r)) for r in rows])
         else:
             poly = self.enc.encode(arr)
-        self._home()
-        return self._set_d(self.ctx.mul_plain(a, poly), self._d(a) + 1)
+        with span(HE + "mul_plain", blocks=self._nblocks(a)):
+            self._home()
+            out = self.ctx.mul_plain(a, poly)
+        return self._set_d(out, self._d(a) + 1)
 
     def add_plain(self, a, vec):
         self._charge("add", a)
@@ -553,8 +562,10 @@ class BFVBackend(_BackendBase):
             else:
                 self._charge("add", ct)
                 noise = self.model.add(noise, term)
-        out = self.ctx._like(terms[0][0], self.ctx.dot_scalars(
-            [ct.data for ct in cts], cs), noise)
+        with span(HE + "dot_plain",
+                  blocks=max(self._nblocks(ct) for ct, _ in terms)):
+            data = self.ctx.dot_scalars([ct.data for ct in cts], cs)
+        out = self.ctx._like(terms[0][0], data, noise)
         return self._set_d(out, max(self._d(ct) for ct, _ in terms))
 
     # -- data movement ---------------------------------------------------
@@ -563,18 +574,19 @@ class BFVBackend(_BackendBase):
         hops = bin(step % (self.slots // 2)).count("1")
         self._charge("rotate", a, mult=hops)
         self._charge_gather(a, mult=hops)      # one kswitch per pow-2 hop
-        self._home()
-        return self._set_d(
-            self.ctx.rotate_rows(a, step, self.keys.gks,
-                                 mesh=self._limb_mesh()), self._d(a))
+        with span(HE + "rotate", blocks=self._nblocks(a)):
+            self._home()
+            out = self.ctx.rotate_rows(a, step, self.keys.gks,
+                                       mesh=self._limb_mesh())
+        return self._set_d(out, self._d(a))
 
     def swap_rows(self, a):
         self._charge("rotate", a)
         self._charge_gather(a)
-        self._home()
-        return self._set_d(
-            self.ctx.swap_rows(a, self.keys.gks, mesh=self._limb_mesh()),
-            self._d(a))
+        with span(HE + "swap_rows", blocks=self._nblocks(a)):
+            self._home()
+            out = self.ctx.swap_rows(a, self.keys.gks, mesh=self._limb_mesh())
+        return self._set_d(out, self._d(a))
 
 
 # ---------------------------------------------------------------------------

@@ -37,10 +37,11 @@ byte-identical to the fault-free run.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 
-from ..runtime import faults
+from ..runtime import faults, spans
 from . import ops
 from .physical import (CmpAtom, annotate_downstream, compile_mask,
                        run_mask_node)
@@ -101,6 +102,7 @@ class ExecReport:
             "add": after.add - before.add,
             "rotate": after.rotate - before.rotate,
             "launches": after.launches - before.launches,
+            "dispatches": after.dispatches - before.dispatches,
             "refresh": after.refresh - before.refresh,
             "max_depth": after.max_depth,
         })
@@ -254,22 +256,29 @@ class Executor:
         self._guards = False          # decrypt-boundary guards armed?
         self._sentinel = None         # plaintext sentinel lane (guarded)
         self._verify_report = None    # static VerifyReport of the last run
+        self.run_id = 0               # query id the spans of a run share
 
     # ------------------------------------------------------------ public
     def run(self, plan: QueryPlan, validate: bool = True) -> dict:
-        cq = self.compile(plan)
-        self._static_verify(cq, mirror_begin_run=True, warm=False)
-        if self.pl.optimized and self.pl.share_masks:
-            # New serve epoch: masks derived by earlier runs on this
-            # planner's cache now count as cross-query hits.
-            self.pl.mask_cache.begin_run()
-        return self._run(cq, validate, warm=False)
+        self.run_id = spans.next_run()
+        with spans.span(spans.QUERY, run=self.run_id, plan=plan.name):
+            with spans.span(spans.ADMIT, run=self.run_id):
+                with spans.span(spans.COMPILE):
+                    cq = self.compile(plan)
+                self._static_verify(cq, mirror_begin_run=True, warm=False)
+                if self.pl.optimized and self.pl.share_masks:
+                    # New serve epoch: masks derived by earlier runs on
+                    # this planner's cache now count as cross-query hits.
+                    self.pl.mask_cache.begin_run()
+            return self._run(cq, validate, warm=False)
 
     def run_compiled(self, cq: CompiledQuery, validate: bool = True) -> dict:
         """Workload path: atoms were requested and flushed batch-wide by
         `run_workload`; execute against the warm shared evaluator."""
-        self._static_verify(cq, mirror_begin_run=False, warm=True)
-        return self._run(cq, validate, warm=True)
+        self.run_id = spans.next_run()
+        with spans.span(spans.QUERY, run=self.run_id, plan=cq.plan.name):
+            self._static_verify(cq, mirror_begin_run=False, warm=True)
+            return self._run(cq, validate, warm=True)
 
     def _static_verify(self, cq: CompiledQuery, mirror_begin_run: bool,
                        warm: bool) -> None:
@@ -281,8 +290,9 @@ class Executor:
         if not getattr(self.pl, "verify_plans", True):
             return
         from .verify import verify_compiled
-        rep = verify_compiled(self.pl, cq, mirror_begin_run=mirror_begin_run,
-                              warm=warm)
+        with spans.span(spans.VERIFY):
+            rep = verify_compiled(self.pl, cq,
+                                  mirror_begin_run=mirror_begin_run, warm=warm)
         self._verify_report = rep
         rep.raise_on_error()
 
@@ -537,11 +547,20 @@ class Executor:
         return [b for d in gmasks.values() for blocks in d.values()
                 for b in blocks]
 
+    @contextlib.contextmanager
+    def _stage(self, label: str):
+        """One DAG stage: its `nshedb.stage:<label>` span, and its op
+        counts as an ExecReport.history entry when it completes."""
+        stats = self.bk.stats
+        before = stats.clone()
+        with spans.span(spans.STAGE + label, run=self.run_id):
+            yield
+        self.report.record(label, before, stats.clone())
+
     def _execute(self, cq: CompiledQuery, warm: bool = False,
                  ckpt: StageCheckpoint | None = None) -> dict:
         pl, bk = self.pl, self.bk
         plan, fact = cq.plan, cq.fact
-        stats = bk.stats
         group_cols, per_col_items = cq.group_cols, cq.per_col_items
         where_expr, where_node, aux_nodes = (cq.where_expr, cq.where_node,
                                              cq.aux_nodes)
@@ -558,21 +577,19 @@ class Executor:
             ev = self.ev if self.ev is not None else pl.evaluator()
             if not ckpt.has("atoms"):
                 faults.maybe_device_loss("atoms")
-                snap = stats.clone()
-                if not warm:
-                    self.request_atoms(cq, ev)
-                    ev.flush()
-                self.report.record("atoms[fused]", snap, stats.clone())
+                with self._stage("atoms[fused]"):
+                    if not warm:
+                        self.request_atoms(cq, ev)
+                        ev.flush()
                 ckpt.put("atoms", True)
 
             if ckpt.has("where"):
                 where = ckpt.get("where")
             else:
                 faults.maybe_device_loss("where")
-                snap = stats.clone()
-                where = (run_mask_node(where_node, ev, pl)
-                         if where_node is not None else None)
-                self.report.record("where", snap, stats.clone())
+                with self._stage("where"):
+                    where = (run_mask_node(where_node, ev, pl)
+                             if where_node is not None else None)
                 ckpt.put("where", where, blocks=where or ())
 
             aux = {}
@@ -582,21 +599,21 @@ class Executor:
                     aux[name] = ckpt.get(stage)
                     continue
                 faults.maybe_device_loss(stage)
-                snap = stats.clone()
-                aux[name] = self._translate_aux(a, node, ev, None)
-                self.report.record(stage, snap, stats.clone())
+                with self._stage(stage):
+                    aux[name] = self._translate_aux(a, node, ev, None)
                 ckpt.put(stage, aux[name], blocks=aux[name])
 
             if ckpt.has("gmasks"):
                 gmasks = ckpt.get("gmasks")
             elif group_cols:
                 faults.maybe_device_loss("gmasks")
-                gmasks = {
-                    col: dict(ev.eq_masks(fact, col,
-                                          [vid for _n, vid in items],
-                                          need_levels=cq.inject_layers))
-                    for col, items in zip(group_cols, per_col_items)
-                }
+                with self._stage("gmasks"):
+                    gmasks = {
+                        col: dict(ev.eq_masks(fact, col,
+                                              [vid for _n, vid in items],
+                                              need_levels=cq.inject_layers))
+                        for col, items in zip(group_cols, per_col_items)
+                    }
                 ckpt.put("gmasks", gmasks,
                          blocks=self._gmask_blocks(gmasks))
             else:
@@ -608,10 +625,9 @@ class Executor:
                 where = ckpt.get("where")
             else:
                 faults.maybe_device_loss("where")
-                snap = stats.clone()
-                where = (pl.where_mask(fact, where_expr)
-                         if where_expr is not None else None)
-                self.report.record("where[seq]", snap, stats.clone())
+                with self._stage("where[seq]"):
+                    where = (pl.where_mask(fact, where_expr)
+                             if where_expr is not None else None)
                 ckpt.put("where", where, blocks=where or ())
             aux = {}
             for name, (a, node) in aux_nodes.items():
@@ -620,21 +636,22 @@ class Executor:
                     aux[name] = ckpt.get(stage)
                     continue
                 faults.maybe_device_loss(stage)
-                snap = stats.clone()
-                fk_ov = (ops.mask_columns(bk, fact.col(a.hop.fk).blocks, where)
-                         if where is not None else None)
-                aux[name] = self._translate_aux(a, node, None, fk_ov)
-                self.report.record(f"{stage}[pushdown]", snap, stats.clone())
+                with self._stage(f"{stage}[pushdown]"):
+                    fk_ov = (ops.mask_columns(bk, fact.col(a.hop.fk).blocks,
+                                              where)
+                             if where is not None else None)
+                    aux[name] = self._translate_aux(a, node, None, fk_ov)
                 ckpt.put(stage, aux[name], blocks=aux[name])
             if ckpt.has("gmasks"):
                 gmasks = ckpt.get("gmasks")
             elif group_cols:
                 faults.maybe_device_loss("gmasks")
-                gmasks = {
-                    col: dict(ops.group_masks(bk, fact, col,
-                                              [vid for _n, vid in items]))
-                    for col, items in zip(group_cols, per_col_items)
-                }
+                with self._stage("gmasks[seq]"):
+                    gmasks = {
+                        col: dict(ops.group_masks(bk, fact, col,
+                                                  [vid for _n, vid in items]))
+                        for col, items in zip(group_cols, per_col_items)
+                    }
                 ckpt.put("gmasks", gmasks,
                          blocks=self._gmask_blocks(gmasks))
             else:
@@ -644,11 +661,9 @@ class Executor:
         # decrypted results themselves, which must re-derive under any
         # recovery so the guards re-check them.
         faults.maybe_device_loss("aggregate")
-        snap = stats.clone()
-        out = (self._grouped(plan, fact, per_col_items, gmasks, where, aux)
-               if group_cols else self._ungrouped(plan, fact, where))
-        self.report.record("aggregate", snap, stats.clone())
-        return out
+        with self._stage("aggregate"):
+            return (self._grouped(plan, fact, per_col_items, gmasks, where, aux)
+                    if group_cols else self._ungrouped(plan, fact, where))
 
     def _translate_aux(self, a, node, ev, fk_override):
         """Aux mask: parent-table subtree -> translated fact mask."""

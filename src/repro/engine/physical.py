@@ -44,6 +44,7 @@ import dataclasses
 import math
 
 from ..core import compare as cmp
+from ..runtime.spans import CIRCUIT, span
 from .plan import And, JoinHop, Not, Or, Pred, Translated
 from .storage import EncryptedTable
 
@@ -290,10 +291,12 @@ class AtomEvaluator:
         out = []
         for i in range(0, len(blocks), step):
             chunk = blocks[i:i + step]
-            if len(chunk) == 1:
-                out.append(self._circuit(kind, chunk[0]))
-            else:
-                out += bk.unstack_blocks(self._circuit(kind, bk.stack_blocks(chunk)))
+            with span(CIRCUIT + kind, lanes=len(chunk)):
+                if len(chunk) == 1:
+                    out.append(self._circuit(kind, chunk[0]))
+                else:
+                    out += bk.unstack_blocks(
+                        self._circuit(kind, bk.stack_blocks(chunk)))
         return out
 
     def flush(self) -> None:

@@ -111,6 +111,27 @@ def test_lane_program_on_data_mesh_has_no_collective(v5e_2x2):
             assert op not in text, (B, op)
 
 
+def test_keyswitch_ntt_is_named_in_the_rotation(one_chip):
+    """The rotation's key-switch NTT kernel carries its `jax.named_scope`
+    names in the compiled program's op_name, where a trace reads them."""
+    import re
+    from repro.core.bfv import BFVContext
+    from repro.core.params import make_params
+    p = make_params(n=1024, t=65537, k=4)
+    ctx = BFVContext(p, backend="pallas", interpret=False)
+    shape = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    lq = jax.tree.map(shape, ctx.limb_q)
+    ksk = jax.ShapeDtypeStruct((p.k, p.k, p.n), jnp.int64, sharding=one_chip)
+    src, sign = jax.tree.map(shape, ctx._galois_tabs[p.rowswap_g])
+    data = jax.ShapeDtypeStruct((2, p.k, p.n), jnp.int64, sharding=one_chip)
+    text = ctx._rotate_j.lower(lq, ksk, ksk, src, sign, data).compile().as_text()
+    names = [m.group(1) for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             for m in [re.search(r'op_name="([^"]*)"', line)] if m]
+    assert any("he.keyswitch/he.ntt/" in n for n in names), names
+    assert any("he.keyswitch/he.intt/" in n for n in names), names
+
+
 def test_stage_twiddles_match_reference_order():
     """Host-side table expansion: every stage entry is the twiddle the
     reference butterfly (core/ntt.py) applies to that flat index."""
