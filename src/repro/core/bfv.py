@@ -451,15 +451,19 @@ class BFVContext:
     DOT_TERMS = 32      # terms per inner-product program (one compile)
 
     @jax.named_scope("he.dot")
-    def _dot_impl(self, lq, acc, datas, cs):
-        # acc < 2^30 plus DOT_TERMS terms < 2^30 * 2^17: below 2^60
-        return lq.reduce(acc + sum(d * cs[i] for i, d in enumerate(datas)))
+    def _dot_impl(self, lq, cs, acc, *datas):
+        return lq.dot(acc, datas, cs)
 
     def dot_scalars(self, datas: list, coeffs: list):
         """sum_i coeffs[i] * datas[i] over residues, coefficients in [0, t)
         (< 2^17), as a few programs of DOT_TERMS terms each (the last
         padded with zero coefficients), so every length shares one
-        compilation."""
+        compilation.  A program runs on any block batch; on a data mesh
+        it runs under shard_map (`_lane_map_mesh`), since XLA cannot
+        partition the kernel."""
+        if self.limb_q.backend == "pallas" and self.params.t > 1 << 17:
+            raise ValueError(f"the inner-product kernel takes coefficients "
+                             f"below 2^17; t = {self.params.t}")
         g = self.DOT_TERMS
         acc = jnp.zeros_like(datas[0])
         coeffs = list(coeffs)
@@ -467,8 +471,12 @@ class BFVContext:
             part = datas[i:i + g]
             pad = g - len(part)
             cs = jnp.asarray(coeffs[i:i + g] + [0] * pad, dtype=jnp.int64)
-            acc = self._dispatch(self._dot_j, self.limb_q, acc,
-                                 tuple(part + [part[0]] * pad), cs)
+            args = (cs, acc, *part, *[part[0]] * pad)
+            if self.mesh is None:
+                acc = self._dispatch(self._dot_j, self.limb_q, *args)
+            else:
+                acc = self._lane_map_mesh(self._dot_j, (self.limb_q,), args,
+                                          [False] + [x.ndim == 4 for x in args[1:]])
         return acc
 
     def add(self, a, b):

@@ -4,7 +4,8 @@
 primitives of BFV evaluation — pointwise mul/add/sub-mod and the
 forward/inverse negacyclic NTT — either through the Pallas kernels
 (`kernels/modops`, `kernels/ntt`) or through the pure-jnp `*_ref`
-oracles, selected by a backend flag:
+oracles, selected by a backend flag (and the plaintext-scalar inner
+product `dot` of the LT's baby-step sums likewise):
 
     "ref"     exact int64 jnp arithmetic (always available)
     "pallas"  uint32 Barrett/Shoup kernels; interpret mode off-TPU,
@@ -34,7 +35,8 @@ from . import ntt as nttm
 from .params import NttTables
 from ..kernels import resolve_interpret
 from ..kernels.u32 import barrett_precompute, barrett_reduce
-from ..kernels.modops.modops import add_mod_pallas, mul_mod_pallas, sub_mod_pallas
+from ..kernels.modops.modops import (add_mod_pallas, dot_mod_pallas, mul_mod_pallas,
+                                     sub_mod_pallas)
 from ..kernels.ntt.ntt import ntt_fwd_pallas, ntt_inv_pallas
 from ..kernels.ntt.ops import kernel_tables
 
@@ -204,6 +206,23 @@ class LimbOps:
             a, b,
             lambda x, y, q: sub_mod_pallas(x, y, q, interpret=self.interpret),
             lambda x, y: (x - y) % self._row_q(x))
+
+    def dot(self, acc, datas, cs):
+        """(acc + sum_i cs[i] * datas[i]) mod q over (..., k, n): residues
+        in [0, q), cs a (T,) array of plaintext scalars < 2^17.  One
+        uint32 multiply-accumulate kernel call for any batch on the
+        kernel backend, exact int64 on the reference."""
+        if self._use_ref():
+            return (acc + sum(d * cs[i] for i, d in enumerate(datas))) % self.q[:, None]
+        shape = jnp.broadcast_shapes(acc.shape, *(d.shape for d in datas))
+        rows = lambda x: self._rows(jnp.broadcast_to(x, shape))[0].astype(jnp.uint32)
+        a = rows(acc)
+        B = a.shape[0] // self.k
+        out = dot_mod_pallas(a, [rows(d) for d in datas], cs.astype(jnp.uint32),
+                             self._tile(self.arrays["q_col"], B),
+                             self._tile(self.arrays["mu_col"], B),
+                             interpret=self.interpret)
+        return out.astype(jnp.int64).reshape(shape)
 
     def reduce(self, x):
         """x mod q over (..., k, n) for int64 x in [0, 2^60): the uint32

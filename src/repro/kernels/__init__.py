@@ -14,7 +14,8 @@ float-only; the NTT stays on the VPU with exact integer ops.
 Kernels:
   ntt            negacyclic NTT, whole polynomial VMEM-resident, radix-2
                  stages in-kernel, grid over (batch x limb)
-  modops         dyadic (pointwise) ciphertext ops: Barrett modmul/add/sub
+  modops         dyadic (pointwise) ciphertext ops: Barrett modmul/add/sub,
+                 and the plaintext-scalar inner product of the LT circuit
   rotate_reduce  log-depth packed aggregation (the paper's rotate+add sum)
   flash_attn     blocked online-softmax attention for the LM substrate
                  (causal / local-window / logit-softcap variants)

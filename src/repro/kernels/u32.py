@@ -9,6 +9,8 @@ from 16-bit limb splitting on uint32 vectors:
   barrett_mulmod general a * b mod q for q in (2^28, 2^30): full 60-bit
                  product in (hi, lo) halves, quotient via mu = 2^60 / q
   barrett_reduce the same reduction of a 60-bit int64 value
+  dot_mod        (acc + sum_i c_i * d_i) mod q for c_i < 2^17: an exact
+                 sum in (hi, lo) word pairs, one Barrett tail
 
 All functions are shape-polymorphic jnp code: they run identically inside
 Pallas kernel bodies and in host-side tests.
@@ -91,6 +93,34 @@ def barrett_reduce(x, q, mu):
     lo = (x & 0xFFFFFFFF).astype(jnp.uint32)
     x1 = (x >> 29).astype(jnp.uint32)                  # floor(x / 2^29) < 2^31
     return _barrett_tail(lo, x1, q, mu)
+
+
+def _add_wide(hi, lo, x):
+    """(hi, lo) + x for a 64-bit value held as two uint32 words."""
+    lo = lo + x
+    return hi + (lo < x).astype(jnp.uint32), lo
+
+
+def dot_mod(acc, terms, coeffs, q, mu):
+    """(acc + sum_i coeffs[i] * terms[i]) mod q on uint32 lanes.
+
+    acc, terms[i] < q in (2^28, 2^30); coeffs[i] < 2^17 (plaintext
+    scalars mod t <= 2^17), any broadcastable shapes.  With d = dh*2^15
+    + dl, both dl*c and dh*c are below 2^32: two 32-bit multiplies a
+    term, each summed exactly in a (hi, lo) pair of words.  The total
+    stays below 2^60, the Barrett window, for up to 2^12 terms; one
+    `_barrett_tail` reduces it.
+    """
+    lo = acc.astype(jnp.uint32)                 # (lo_hi, lo): sum c * dl
+    lo_hi = up_hi = up = jnp.zeros_like(lo)     # (up_hi, up): sum c * dh
+    for d, c in zip(terms, coeffs):
+        d = d.astype(jnp.uint32)
+        c = jnp.asarray(c).astype(jnp.uint32)
+        lo_hi, lo = _add_wide(lo_hi, lo, (d & 0x7FFF) * c)
+        up_hi, up = _add_wide(up_hi, up, (d >> 15) * c)
+    # x = (lo_hi, lo) + (up_hi, up) * 2^15
+    hi, lo = _add_wide(lo_hi + (up_hi << 15) + (up >> 17), lo, up << 15)
+    return _barrett_tail(lo, (hi << 3) | (lo >> 29), q, mu)
 
 
 def add_mod(a, b, q):

@@ -39,6 +39,17 @@ def test_mulhi_property(a, b):
     assert got == (a * b) >> 32
 
 
+@given(st.integers(0, PRIME30 - 1),
+       st.lists(st.tuples(st.integers(0, PRIME30 - 1), st.integers(0, 2**17 - 1)),
+                max_size=40))
+@settings(max_examples=200, deadline=None)
+def test_dot_mod_property(acc, pairs):
+    mu = u32.barrett_precompute(PRIME30)
+    got = int(u32.dot_mod(jnp.uint32(acc), [jnp.uint32(d) for d, _ in pairs],
+                          [c for _, c in pairs], jnp.uint32(PRIME30), jnp.uint32(mu)))
+    assert got == (acc + sum(d * c for d, c in pairs)) % PRIME30
+
+
 @pytest.mark.parametrize("n,k", [(64, 1), (128, 2), (256, 3), (512, 2)])
 def test_ntt_kernel_sweep(n, k):
     from repro.kernels.ntt import ops as ntt_ops
@@ -69,6 +80,42 @@ def test_modops_kernel_sweep(rows, n):
                     (mod_ops.sub_mod, mod_ref.sub_mod_ref)]:
         got = op(a, b, primes)
         assert np.array_equal(np.asarray(got), np.asarray(ref(a, b, q)))
+
+
+@pytest.mark.parametrize("case", ["random", "max", "zero_coeffs", "padded"])
+@pytest.mark.parametrize("rows,n", [(30, 1024), (31, 2048), (60, 4096)])
+def test_dot_kernel_sweep(rows, n, case):
+    """The inner-product kernel (32 terms, as the engine runs it) against
+    a numpy int64 reference; rows 31 is off the 8-row tiling, 60 is one
+    ciphertext at k = 30.  Edge cases: every term and acc at q - 1 with
+    c = t - 1 = 2^16; all coefficients zero; the last terms padded with
+    a repeat of the first at coefficient zero, as dot_scalars pads."""
+    from repro.core.mathutil import find_ntt_primes
+    from repro.kernels.modops.modops import dot_mod_pallas
+    from repro.kernels.modops.ref import dot_mod_ref
+    T = 32
+    primes = find_ntt_primes(n, 30, rows)
+    q = np.array(primes, dtype=np.int64)[:, None]
+    rng = np.random.default_rng(rows * n)
+    acc = rng.integers(0, q, (rows, n))
+    terms = [rng.integers(0, q, (rows, n)) for _ in range(T)]
+    cs = rng.integers(0, 65537, T)
+    if case == "max":
+        acc = np.broadcast_to(q - 1, (rows, n))
+        terms = [acc] * T
+        cs = np.full(T, 65536)
+    elif case == "zero_coeffs":
+        cs = np.zeros(T, dtype=np.int64)
+    elif case == "padded":
+        terms = terms[:20] + [terms[0]] * 12
+        cs[20:] = 0
+    exp = dot_mod_ref(acc, terms, cs, q[:, 0])          # numpy int64
+    mu = np.array([u32.barrett_precompute(p) for p in primes], dtype=np.uint32)[:, None]
+    got = dot_mod_pallas(jnp.asarray(acc, dtype=jnp.uint32),
+                         [jnp.asarray(d, dtype=jnp.uint32) for d in terms],
+                         jnp.asarray(cs, dtype=jnp.uint32),
+                         jnp.asarray(q, dtype=jnp.uint32), jnp.asarray(mu))
+    assert np.array_equal(np.asarray(got).astype(np.int64), exp)
 
 
 @pytest.mark.parametrize("rows,n,chunk", [(2, 256, None), (4, 1024, None),

@@ -1,6 +1,7 @@
 """Kernel <-> reference parity for the batched limb-op dispatch layer.
 
-Exercises the Pallas `mul_mod/add_mod/sub_mod` and forward/inverse NTT
+Exercises the Pallas `mul_mod/add_mod/sub_mod`, the inner product
+`dot_mod` and forward/inverse NTT
 kernels (interpret mode off-TPU) against the pure-jnp `*_ref` oracles
 through `core/limbops.LimbOps`, across several limb counts, batch
 shapes, non-tile-aligned lengths, and edge values (0, q-1).
@@ -67,6 +68,21 @@ def test_pointwise_parity_batched(param_grid, op, batch):
     loop = np.stack([np.asarray(getattr(pal, op)(x, y))
                      for x, y in zip(flat_a, flat_b)])
     assert np.array_equal(got.reshape(loop.shape), loop)
+
+
+@pytest.mark.parametrize("batch", [(2,), (3, 2)], ids=["ciphertext", "blocks"])
+def test_dot_parity(param_grid, batch):
+    """The 32-term inner product: one kernel call over a ciphertext
+    (2, k, n) or a batch of blocks (3, 2, k, n) equals the int64 ref,
+    with zero (padding) and maximal (< 2^17) coefficients among them."""
+    rng = np.random.default_rng(17)
+    for p, ref, pal in param_grid:
+        acc = _rand(rng, p.Q.primes, batch, p.n)
+        datas = [_rand(rng, p.Q.primes, batch, p.n) for _ in range(32)]
+        cs = jnp.asarray(np.r_[rng.integers(0, 1 << 17, 30), 0, (1 << 17) - 1])
+        got = np.asarray(pal.dot(acc, datas, cs))
+        assert got.shape == acc.shape
+        assert np.array_equal(got, np.asarray(ref.dot(acc, datas, cs))), (p.n, batch)
 
 
 def test_pointwise_edge_values(param_grid):
@@ -150,6 +166,20 @@ def test_both_bases_in_window_and_outside_raises():
     with pytest.raises(ValueError, match="Barrett|2\\^28"):
         resolve_backend("pallas", wide)
     assert resolve_backend("ref", wide) == "ref"
+
+
+def test_dot_kernel_refuses_coefficients_past_2_17():
+    """The inner-product kernel's exact sum holds for coefficients below
+    2^17; a plaintext modulus above that raises on the kernel backend
+    instead of wrapping, and the int64 reference still runs it."""
+    from repro.core.bfv import BFVContext
+    p = make_params(n=64, t=786433, k=1)
+    x = [jnp.ones((2, 1, 64), dtype=jnp.int64)]
+    with pytest.raises(ValueError, match="2\\^17"):
+        BFVContext(p, backend="pallas").dot_scalars(x, [p.t - 1])
+    got = BFVContext(p, backend="ref").dot_scalars(x, [p.t - 1])
+    assert np.array_equal(np.asarray(got), (p.t - 1) % np.asarray(p.Q.q)[:, None]
+                          * np.ones((2, 1, 64), dtype=np.int64))
 
 
 def test_resolve_backend_flags():

@@ -496,7 +496,8 @@ def test_lane_map_on_data_mesh_equals_one_device(bfv_micro):
     """On a data mesh the kernel programs run under shard_map, each
     device mapping the one-ciphertext program over its own lanes: same
     residues as the one-device lane loop, results still split by lane,
-    and a lone ciphertext runs replicated."""
+    and a lone ciphertext runs replicated.  The inner product too, with
+    lone and batched terms mixed."""
     bk = bfv_micro
     blocks = [bk.encrypt(np.arange(bk.slots) % 11 + i) for i in range(4)]
     one = bk.ctx.stack_cts(blocks)
@@ -505,6 +506,9 @@ def test_lane_map_on_data_mesh_equals_one_device(bfv_micro):
     want_dec = bk.decrypt(blocks[2])
     want_pair = np.asarray(bk.ctx.mul_plain(bk.ctx.stack_cts(blocks[:2]),
                                             np.arange(bk.slots) % 5).data)
+    dot_cs = [3, 0, bk.t - 1]
+    want_dot = np.asarray(bk.ctx.dot_scalars(
+        [one.data, one.data[::-1], blocks[1].data], dot_cs))
     with activate(bk, make_shard_context(2)):
         batch = bk.stack_blocks(blocks)                 # two lanes per device
         got_mul = bk.mul(batch, blocks[1]).data
@@ -512,6 +516,8 @@ def test_lane_map_on_data_mesh_equals_one_device(bfv_micro):
         got_dec = bk.decrypt(blocks[2])
         pair = bk.stack_blocks(blocks[:2])              # one lane per device
         got_pair = bk.ctx.mul_plain(pair, np.arange(bk.slots) % 5).data
+        got_dot = bk.ctx.dot_scalars(
+            [batch.data, batch.data[::-1], blocks[1].data], dot_cs)
         assert bk.ctx.mesh is not None
     assert len(got_mul.sharding.device_set) == 2
     assert not got_mul.sharding.is_fully_replicated
@@ -519,3 +525,4 @@ def test_lane_map_on_data_mesh_equals_one_device(bfv_micro):
     np.testing.assert_array_equal(np.asarray(got_rot), want_rot)
     np.testing.assert_array_equal(got_dec, want_dec)
     np.testing.assert_array_equal(np.asarray(got_pair), want_pair)
+    np.testing.assert_array_equal(np.asarray(got_dot), want_dot)

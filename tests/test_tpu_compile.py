@@ -62,6 +62,17 @@ def test_modops_compiles(one_chip, rows, op):
         _compile(lambda a, b, q: kern(a, b, q, interpret=False), a, a, col)
 
 
+@pytest.mark.parametrize("rows", ROWS + (60,))
+def test_dot_compiles(one_chip, rows):
+    """The 32-term inner product; 60 rows is one ciphertext at k = 30."""
+    from repro.kernels.modops import modops
+    a = _u32((rows, N), one_chip)
+    col = _u32((rows, 1), one_chip)
+    fn = lambda cs, acc, q, mu, *ts: modops.dot_mod_pallas(acc, ts, cs, q, mu,
+                                                          interpret=False)
+    _compile(fn, _u32((32,), one_chip), a, col, col, *[a] * 32)
+
+
 @pytest.mark.parametrize("rows", ROWS)
 @pytest.mark.parametrize("inverse", [False, True], ids=["fwd", "inv"])
 def test_ntt_compiles(one_chip, rows, inverse):
@@ -109,6 +120,33 @@ def test_lane_program_on_data_mesh_has_no_collective(v5e_2x2):
         assert "tpu_custom_call" in text
         for op in ("all-gather", "all-reduce", "collective-permute", "all-to-all"):
             assert op not in text, (B, op)
+
+
+def test_dot_on_data_mesh_has_no_collective(v5e_2x2):
+    """On a 4-chip data mesh the inner product runs under shard_map
+    (`dot_scalars` through `_lane_map_mesh`): its kernel compiles, named
+    under `he.dot`, and nothing moves between chips."""
+    import re
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.core.bfv import BFVContext
+    from repro.core.params import make_params
+    p = make_params(n=1024, t=65537, k=4)
+    ctx = BFVContext(p, backend="pallas", interpret=False)
+    ctx.mesh = Mesh(np.array(v5e_2x2.devices), ("data",))
+    rep, lanes = (NamedSharding(ctx.mesh, P()), NamedSharding(ctx.mesh, P("data")))
+    lq = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+                      ctx.limb_q)
+    cs = jax.ShapeDtypeStruct((ctx.DOT_TERMS,), jnp.int64, sharding=rep)
+    batch = jax.ShapeDtypeStruct((4, 2, p.k, p.n), jnp.int64, sharding=lanes)
+    nb = ctx.DOT_TERMS + 1
+    fn = ctx._lane_program(ctx._dot_j, 1, (False,) + (True,) * nb, True)
+    text = fn.lower(lq, cs, *[batch] * nb).compile().as_text()
+    names = [m.group(1) for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             for m in [re.search(r'op_name="([^"]*)"', line)] if m]
+    assert names and all("he.dot/" in n for n in names), names
+    for op in ("all-gather", "all-reduce", "collective-permute", "all-to-all"):
+        assert op not in text, op
 
 
 def test_keyswitch_ntt_is_named_in_the_rotation(one_chip):
