@@ -17,9 +17,16 @@ is imported here):
 
 plus its counters (`BFVBackend.stats`, an OpStats) and the ciphertext
 arrays a loaded table holds.
+
+A configuration whose `planner` states `shards` runs every planner with
+that many data shards, one per chip of the cell.  On such a mesh
+`stored_bytes_per_row` is still the table's ciphertext bytes, summed
+over every chip that holds them, over its rows, and `memory_peak_bytes`
+is the peak of the fullest chip.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import gc
 import importlib.util
@@ -39,8 +46,7 @@ from .peaks import peaks
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 TRACE = "/jax/core/compile/jaxpr_trace_duration"
 LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
-OP_FIELDS = ("mul", "mul_plain", "mul_scalar", "add", "rotate", "encrypt",
-             "decrypt", "refresh", "launches")
+MAXIMA = ("max_depth",)    # OpStats fields that are maxima, not counters
 ADMIT_MIN_S = 0.25        # host clock: repeat admission for at least this long
 
 
@@ -109,7 +115,9 @@ class RunRecord:
     cell: str
     queries: int
     window_s: float
-    ops: dict                     # OpStats deltas over the window
+    ops: collections.Counter      # every OpStats counter's delta over
+                                  # the window (a field the program lacks
+                                  # reads 0); maxima as after the window
     window_compiles: list         # programs compiled or loaded in it
     admit_s: list | None          # host seconds per admission (traced run)
     trace: "xplane.Summary | None"
@@ -178,8 +186,13 @@ class Client:
         self.shared = self._planner() if mix["planner"] == "shared" else None
 
     def _planner(self):
+        """A planner as the configuration states it: `planner.shards`,
+        where given, partitions every stacked column over a data mesh of
+        that many chips; without it the planner runs on one chip."""
         from repro.engine.planner import Planner
-        return Planner(self.db, optimized=bool(self.cfg["planner"]["optimized"]))
+        conf = self.cfg["planner"]
+        kw = {"shards": int(conf["shards"])} if "shards" in conf else {}
+        return Planner(self.db, optimized=bool(conf["optimized"]), **kw)
 
     def planner(self):
         return self.shared if self.shared is not None else self._planner()
@@ -223,7 +236,16 @@ def admission_seconds(client: Client, plan) -> list:
 
 
 def _ops(bk) -> dict:
-    return {f: getattr(bk.stats, f) for f in OP_FIELDS}
+    """Every field of the backend's OpStats, by name."""
+    return {f.name: getattr(bk.stats, f.name)
+            for f in dataclasses.fields(bk.stats)}
+
+
+def window_ops(before: dict, after: dict) -> collections.Counter:
+    """Each counter's delta from `before` to `after`; a maximum keeps
+    its value at `after`.  A name neither holds reads 0."""
+    return collections.Counter({
+        f: v if f in MAXIMA else v - before[f] for f, v in after.items()})
 
 
 def _profile_options():
@@ -290,6 +312,9 @@ def run_cell(cell: dict, cfg: dict, mix: dict, seed: int, seconds: float,
         finally:
             shutil.rmtree(tdir, ignore_errors=True)
         log(f"trace reduced in {time.perf_counter() - t0:.3f} s")
+        log(f"trace by name: scope_s {summary.scope_s}; span_s "
+            f"{summary.span_s}; collective_s {summary.collective_s} "
+            f"collective_bytes {summary.collective_bytes}")
 
     # --- the check, after the window and the memory reading ----------
     t = int(cfg["he"]["t"])
@@ -306,12 +331,12 @@ def run_cell(cell: dict, cfg: dict, mix: dict, seed: int, seconds: float,
     checks = {"wrong_values": {"value": wrong, "limit": 0},
               "refreshes": {"value": refreshes, "limit": 0}}
 
-    ops = {f: ops_w1[f] - ops_w0[f] for f in OP_FIELDS}
+    ops = window_ops(ops_w0, ops_w1)
     log(f"setup phases (s): {phases}")
     log(f"window: {len(queries)} queries in {window_s:.6f} s, durations "
         f"{[round(d, 6) for d in durations]}, programs compiled or loaded "
         f"in the window: {len(counter.names)} {sorted(set(counter.names))}")
-    log(f"window op counts: {ops}; values compared: {expected}")
+    log(f"window op counts: {dict(ops)}; values compared: {expected}")
     log(f"window host pauses (offset from window start): {counter.pauses()}")
     log("params: " + "; ".join(str(q["params"]) for q in queries[:8])
         + (" ..." if len(queries) > 8 else ""))

@@ -11,7 +11,9 @@ checks every answer against the plain reference.  The last line of
 standard output is one JSON object; the numbers compared, each with its
 limit, are the last lines of standard error.  Without a TPU, or with
 fewer chips than the cell asks for, it exits non-zero and prints no
-result.
+result.  A cell whose configuration is laid out for another number of
+chips (its `chips`, or its `planner.shards`, 1 where absent) is refused
+with exit code 2 before set-up.
 """
 from __future__ import annotations
 
@@ -50,6 +52,18 @@ def applies(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
+def layout_mismatch(cell: dict, cfg: dict) -> str | None:
+    """Why the configuration cannot run on the cell's chips, or None."""
+    chips = int(cell["chips"])
+    stated = {"chips": int(cfg.get("chips", 1)),
+              "planner.shards": int(cfg["planner"].get("shards", 1))}
+    for key, n in stated.items():
+        if n != chips:
+            return (f"cell {cell['name']} asks for {chips} chip(s) but its "
+                    f"configuration states {key} = {n}: refusing")
+    return None
+
+
 def require_chips(n: int) -> list:
     """The TPU devices, or SystemExit(2) when there are fewer than n."""
     import jax
@@ -74,6 +88,10 @@ def main(argv=None) -> int:
         if p not in sys.path:
             sys.path.insert(0, p)
     bench, cell, cfg, mix = load_cell(args.workload)
+    mismatch = layout_mismatch(cell, cfg)
+    if mismatch is not None:
+        print(mismatch, file=sys.stderr)
+        return 2
 
     # One fixed cache directory inside the checkout: only a cell's first
     # run there compiles.  The program's own cache setup reads this too.

@@ -11,12 +11,13 @@ have:
 - answer_altered   a decrypted answer is off by one where it is made.
 
 The cell runs on one chip, so there is no exchange between chips to
-leave out.  li32k.q1-cold runs on real BFV (n = 128, t = 65537).  The
-harness's other path, one Q6 re-issued on a shared planner whose mask
-cache the set-up query fills (tests/bench/q6-dashboard.json, no cell),
-runs on the engine's mock backend, which executes the same DAG on
-plaintext with the same 128-slot layout: a cold BFV Q6 takes minutes on
-a CPU.
+leave out here; tests/bench/test_bench_mesh.py leaves it out of the same
+cell's configuration sharded over four devices.  li32k.q1-cold runs on
+real BFV (n = 128, t = 65537).  The harness's other path, one Q6
+re-issued on a shared planner whose mask cache the set-up query fills
+(tests/bench/q6-dashboard.json, no cell), runs on the engine's mock
+backend, which executes the same DAG on plaintext with the same
+128-slot layout: a cold BFV Q6 takes minutes on a CPU.
 """
 import copy
 import os
@@ -139,5 +140,8 @@ def test_q1_cold_traced_run_feeds_the_readers(bfv_q1, monkeypatch):
     assert got["rotations_per_query"] == 66 * 7     # log2(64) hops + row swap
     assert got["admit_ms"] > 0 and 0 <= got["device_idle_share"] < 100
     assert got["kernel_busy_share"] is None and got["ntt_roofline"] is None
+    assert got["dispatches_per_query"] == rec.ops["dispatches"] > 0
+    assert got["keyswitch_ms"] is None          # a CPU trace names no scope
+    assert rec.trace.span_s["nshedb.query"] > 0
     assert set(e2e) == {"query_s", "query_p95_s", "stored_bytes_per_row", "setup_s"}
     assert e2e["stored_bytes_per_row"] == 16 * 2 * 22 * 8   # 16 int64 columns
